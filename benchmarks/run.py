@@ -263,9 +263,10 @@ def bench_pallas_interp() -> None:
         with tempfile.TemporaryDirectory() as td:
             store = TopologyStore(td)
             model = make_pallas_model()
-            runner = PallasRunner(model)
+            runner = PallasRunner(model, interpret=True)
             t0 = time.perf_counter()
-            topo, _ = discover_pallas(runner=runner, n_samples=9, store=store)
+            topo, _ = discover_pallas(runner=runner, interpret=True,
+                                      n_samples=9, store=store)
             cold_s = time.perf_counter() - t0
 
             gt = model.ground_truth()
@@ -288,8 +289,8 @@ def bench_pallas_interp() -> None:
                      and runner.eviction_grid_rows
                      > runner.eviction_grid_calls)
             t0 = time.perf_counter()
-            topo_hit, _ = discover_pallas(runner=runner, n_samples=9,
-                                          store=store)
+            topo_hit, _ = discover_pallas(runner=runner, interpret=True,
+                                          n_samples=9, store=store)
             hit_s = max(time.perf_counter() - t0, 1e-9)
             served = (topo_hit.to_json() == topo.to_json()
                       and runner.kernel_calls == calls)
@@ -678,7 +679,8 @@ def bench_kernels() -> None:
     k = jax.random.normal(ks[1], (1, 2, 256, 64), jnp.float32)
     v = jax.random.normal(ks[2], (1, 2, 256, 64), jnp.float32)
     want, us_ref = _timed(lambda: np.asarray(ref.attention_ref(q, k, v)))
-    got = np.asarray(flash_attention(q, k, v, block_q=128, block_k=128))
+    got = np.asarray(flash_attention(q, k, v, block_q=128, block_k=128,
+                                     interpret=True))
     err = float(np.max(np.abs(got - want)))
     row("kernel_flash_attention", us_ref, f"maxerr={err:.1e}_vs_dense_ref")
 
@@ -688,7 +690,7 @@ def bench_kernels() -> None:
     w = jax.random.uniform(ks[0], (1, 64, 2, 16), jnp.float32, 0.1, 0.95)
     u = jax.random.normal(ks[1], (2, 16), jnp.float32)
     (want_y, _), us_ref = _timed(lambda: ref.wkv6_ref(r, kk, vv, w, u))
-    got_y, _ = ops.wkv6(r, kk, vv, w, u, chunk=16)
+    got_y, _ = ops.wkv6(r, kk, vv, w, u, chunk=16, interpret=True)
     err = float(np.max(np.abs(np.asarray(got_y) - np.asarray(want_y))))
     row("kernel_wkv6", us_ref, f"maxerr={err:.1e}_vs_scan_ref")
 

@@ -3,13 +3,16 @@
     PYTHONPATH=src python examples/discover_topology.py --device sim-h100 -j out.json
     PYTHONPATH=src python examples/discover_topology.py --device host --quick
     PYTHONPATH=src python examples/discover_topology.py --device pallas -p
+    PYTHONPATH=src python examples/discover_topology.py --device tpu -p   # on a TPU
     PYTHONPATH=src python examples/discover_topology.py --device sim-h100 \
         --store /tmp/topo-store        # second run: pure store hit, 0 probes
 
 Mirrors the paper's tool surface: full-suite by default, JSON to stdout,
 optional markdown report, per-family timing like §V-A.  ``--store DIR``
 makes discovery read-/write-through the persistent topology store
-(``--refresh`` forces a re-measure that still writes through).
+(``--refresh`` forces a re-measure that still writes through).  ``tpu``
+measures the attached chip and fails anywhere else; ``pallas`` runs the
+same kernels in the interpreter against a modeled hierarchy.
 """
 import argparse
 import sys
@@ -22,7 +25,7 @@ from repro.core import SIM_DEVICES, discover_host, discover_pallas, discover_sim
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--device", default="sim-h100",
-                    choices=sorted(SIM_DEVICES) + ["host", "pallas"])
+                    choices=sorted(SIM_DEVICES) + ["host", "pallas", "tpu"])
     ap.add_argument("--samples", type=int, default=17)
     ap.add_argument("--elements", nargs="*", default=None,
                     help="restrict to these memory elements (like mt4g CLI)")
@@ -44,6 +47,9 @@ def main() -> None:
     ap.add_argument("-p", "--markdown", action="store_true")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     store = None
     if args.store:
         from repro.core.engine.store import TopologyStore
@@ -63,8 +69,13 @@ def main() -> None:
                                       gc_policy=gc_policy)
     elif args.device == "pallas":
         topo, timings = discover_pallas(n_samples=min(args.samples, 9),
-                                        elements=args.elements, store=store,
+                                        elements=args.elements,
+                                        interpret=True, store=store,
                                         refresh=args.refresh,
+                                        gc_policy=gc_policy)
+    elif args.device == "tpu":
+        topo, timings = discover_pallas(n_samples=min(args.samples, 9),
+                                        store=store, refresh=args.refresh,
                                         gc_policy=gc_policy)
     else:
         dev = SIM_DEVICES[args.device](seed=0)

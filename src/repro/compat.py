@@ -1,57 +1,28 @@
-"""Compatibility shims for JAX API drift.
+"""Auto-typed meshes.
 
-The code targets the current names — ``jax.shard_map(check_vma=...)``,
-``jax.make_mesh(..., axis_types=(AxisType.Auto, ...))`` — but the installed
-runtime may be an older 0.4.x where ``shard_map`` lives in
-``jax.experimental.shard_map`` (with ``check_rep`` instead of ``check_vma``)
-and ``jax.sharding.AxisType`` does not exist (every axis is implicitly Auto,
-which is exactly what the call sites request).  These wrappers resolve to the
-native API when present and degrade losslessly otherwise.
+``jax.make_mesh`` and ``jax.sharding.Mesh`` give every axis
+``AxisType.Explicit`` unless told otherwise.  The sharding rules here
+(``repro.sharding``) place arrays through ``NamedSharding`` and
+``with_sharding_constraint`` and want ``Auto`` axes, so every mesh the
+program builds comes from these two helpers.
 """
 from __future__ import annotations
 
 import jax
 import numpy as np
+from jax.sharding import AxisType
 
-__all__ = ["default_axis_types", "make_mesh", "mesh_from_devices", "shard_map"]
-
-
-def default_axis_types(n: int):
-    """``(AxisType.Auto,) * n`` on new JAX, None (implicit Auto) on old."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    return (axis_type.Auto,) * n if axis_type is not None else None
+__all__ = ["make_mesh", "mesh_from_devices"]
 
 
 def make_mesh(axis_shapes, axis_names, *, devices=None):
-    """``jax.make_mesh`` with Auto axis types wherever the API supports them."""
-    axis_types = default_axis_types(len(axis_names))
-    if axis_types is not None:
-        return jax.make_mesh(axis_shapes, axis_names, devices=devices,
-                             axis_types=axis_types)
-    return jax.make_mesh(axis_shapes, axis_names, devices=devices)
+    """``jax.make_mesh`` with an ``Auto`` type on every axis."""
+    return jax.make_mesh(axis_shapes, axis_names,
+                         axis_types=(AxisType.Auto,) * len(axis_names),
+                         devices=devices)
 
 
 def mesh_from_devices(device_array, axis_names):
     """``jax.sharding.Mesh`` over an explicit device array, Auto-typed."""
-    device_array = np.asarray(device_array)
-    axis_types = default_axis_types(len(axis_names))
-    if axis_types is not None:
-        return jax.sharding.Mesh(device_array, axis_names,
-                                 axis_types=axis_types)
-    return jax.sharding.Mesh(device_array, axis_names)
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool | None = None):
-    """``jax.shard_map``, falling back to ``jax.experimental.shard_map``.
-
-    ``check_vma`` maps onto the old ``check_rep`` flag — both gate the same
-    replication/varying-axis validation pass.
-    """
-    native = getattr(jax, "shard_map", None)
-    if native is not None:
-        kw = {} if check_vma is None else {"check_vma": check_vma}
-        return native(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      **kw)
-    from jax.experimental.shard_map import shard_map as legacy
-    kw = {} if check_vma is None else {"check_rep": check_vma}
-    return legacy(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
+    return jax.sharding.Mesh(np.asarray(device_array), axis_names,
+                             axis_types=(AxisType.Auto,) * len(axis_names))

@@ -43,8 +43,9 @@ TPU_V5E = HardwareSpec(
     ici_link_bandwidth=50e9,
     ici_links_per_chip=4,
     dcn_bandwidth=25e9,
-    vmem_bytes=128 * 1024**2 // 8,   # ~16 MiB VMEM per core
-    smem_bytes=1024 * 1024 // 8,
+    # per TensorCore, as pltpu.get_tpu_info() reports on a v5e chip
+    vmem_bytes=128 * 1024**2,
+    smem_bytes=1024 * 1024,
     notes="v5e: 1 TensorCore/chip, 4 ICI links, 2D torus",
 )
 

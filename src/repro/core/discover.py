@@ -17,9 +17,11 @@ backend-specific:
   ground truth (the validation backend);
 * ``discover_host``   — real CPU measurements through a custom work-item
   plan (the hierarchy has one probeable space, so it skips the registry);
-* ``discover_pallas`` — the ``PallasRunner``: real Pallas kernels
-  (``repro.kernels.pchase_probe``/``stream_probe``) in interpret mode,
-  timed end-to-end against a configured ground-truth hierarchy.
+* ``discover_pallas`` — the Pallas probe kernels
+  (``repro.kernels.pchase_probe``/``stream_probe``): by default compiled
+  for and timed on the attached TPU (``TpuRunner``, nothing modeled);
+  with ``interpret=True`` the ``PallasRunner`` runs them in the
+  interpreter against a configured ground-truth hierarchy (CPU tests).
 
 A fourth path, ``discover_sim_legacy`` (also ``discover_sim(engine=False)``)
 keeps the paper-faithful sequential loop: one probe at a time, exactly as
@@ -60,7 +62,7 @@ __all__ = ["DiscoveryTimings", "DiscoveryRequest", "discover",
            "discover_sim", "discover_sim_legacy", "discover_host",
            "discover_pallas", "spec_from_topology", "default_sweep_budget",
            "sim_request_descriptor", "host_request_descriptor",
-           "pallas_request_descriptor"]
+           "pallas_request_descriptor", "tpu_request_descriptor"]
 
 KIB = 1024
 
@@ -171,11 +173,19 @@ def host_request_descriptor(max_bytes: int, n_samples: int,
             "n_samples": int(n_samples), "quick": bool(quick)}
 
 
+def tpu_request_descriptor(device_kind: str, n_samples: int) -> dict:
+    """Content address of a chip ``discover_pallas`` request: the device
+    kind names the hardware, and its ``pallas-tpu:`` backend keeps it apart
+    from every interpret-mode key."""
+    return {"kind": "discover_pallas", "backend": f"pallas-tpu:{device_kind}",
+            "device_kind": device_kind, "n_samples": int(n_samples)}
+
+
 def pallas_request_descriptor(model, n_samples: int,
                               elements: list[str] | None,
                               budget=_DEFAULT_BUDGET,
                               survey: bool = False, resilience=None) -> dict:
-    """Content address of a ``discover_pallas`` request.
+    """Content address of an interpret-mode ``discover_pallas`` request.
 
     Keyed like the sim descriptor — model identity + seed + sample count +
     element restriction + sweep budget — so Pallas topologies are stored/
@@ -648,7 +658,10 @@ def _assemble_engine_topology(request: DiscoveryRequest, runner, eng,
                     backend=request.backend)
     topo.set_general("clock_domain", request.clock_domain,
                      provenance=PROVENANCE_API)
-    topo.compute.append(ComputeElement("cores_per_sm", runner.cores_per_sm))
+    cores = getattr(runner, "cores_per_sm", None)
+    if cores is not None:
+        topo.compute.append(ComputeElement("cores_per_sm", cores))
+    lat_unit = "ns" if request.clock_domain == "ns" else "cyc"
 
     api_size = getattr(runner, "api_size", lambda _s: None)
 
@@ -758,7 +771,7 @@ def _assemble_engine_topology(request: DiscoveryRequest, runner, eng,
         if isinstance(lat, DegradedResult):
             _mark_degraded(topo, dm, "latency", lat)
         else:
-            dm.set("load_latency", round(lat.p50, 1), "cyc",
+            dm.set("load_latency", round(lat.p50, 1), lat_unit,
                    PROVENANCE_BENCHMARK)
         bw = eng.device_results.get("device_memory_bandwidth")
         if isinstance(bw, DegradedResult):
@@ -769,6 +782,11 @@ def _assemble_engine_topology(request: DiscoveryRequest, runner, eng,
             dm.set("write_bw", round(bw.write_bw / 1e9, 1), "GB/s",
                    PROVENANCE_BENCHMARK)
         topo.memory.append(dm)
+
+    # ---- capacities the runtime reports (API provenance, no probe)
+    for el in getattr(runner, "api_elements", list)():
+        (topo.memory if isinstance(el, MemoryElement)
+         else topo.compute).append(el)
 
     topo.notes.append(
         f"discovery wall time: {eng.wall_seconds:.2f}s (engine; "
@@ -858,39 +876,67 @@ def discover_sim(device, n_samples: int = 33,
 
 
 # --------------------------------------------------------------------------
-# Backend wrappers: Pallas kernels (interpret mode)
+# Backend wrappers: Pallas kernels (the attached TPU, or the interpreter)
 # --------------------------------------------------------------------------
 def discover_pallas(model=None, n_samples: int = 9,
                     elements: list[str] | None = None, *,
-                    runner=None, max_workers: int | None = 0,
+                    interpret=False, runner=None,
+                    max_workers: int | None = 0,
                     store=None, refresh: bool = False,
                     budget=_DEFAULT_BUDGET, fuse: bool = True,
                     gc_policy=None, survey: bool = False, resilience=None,
                     parallel=None,
                     ) -> tuple[Topology, DiscoveryTimings]:
-    """Discovery through the real Pallas probe kernels (third backend).
+    """Discovery through the Pallas probe kernels (third backend).
 
-    Same engine, same registry, same statistics as ``discover_sim`` — the
-    runner is the only moving part, which is the point: the probe stack is
-    genuinely backend-neutral.  ``model`` is the configured ground-truth
-    hierarchy (default ``make_pallas_model()``); pass ``runner`` to reuse a
-    warmed ``PallasRunner`` (compiled kernels) across discoveries.
+    ``interpret=False`` (the default) is the chip path: a ``TpuRunner``
+    compiles the kernels for the attached TPU and measures it — HBM load
+    latency and read/write bandwidth, plus the VMEM/SMEM capacities the
+    runtime reports — with nothing modeled.  It raises unless JAX's first
+    device is a TPU, and takes neither a ``model`` nor the sweep options
+    (there is no cache-family space to sweep).  The topology is named
+    ``pallas-tpu:<device_kind>`` with clock domain ``ns``.
 
-    Kernel launches are the dominant cost of this backend (a timed
-    dispatch plus its calibration twin per sample), so it defaults to the
-    probe-volume optimizers: the adaptive sweep planner
-    (``budget=SweepBudget()``; pass ``budget=None`` to force dense sweeps)
-    and cross-family batch fusion (``fuse=True``), which coalesces every
-    concurrently ready probe round onto one ``pchase_many`` /
-    ``cold_chase_many`` grid launch.  Fused rounds are *executed serially
-    by the coordinator*, preserving the no-co-running-kernels guarantee
-    the inline schedule (``max_workers=0``) provides in unfused mode.
-    Persisted samples are never preloaded (a re-measure is a re-measure).
-    Topologies are content-addressed in the ``TopologyStore`` by
-    ``pallas_request_descriptor`` and served through ``TopologyService``
-    exactly like sim/host ones.
+    ``interpret=True`` (or ``pltpu.InterpretParams()``) runs the same
+    engine, registry and statistics as ``discover_sim`` with a
+    ``PallasRunner`` executing the kernels in the interpreter against a
+    configured ground-truth hierarchy (``model``, default
+    ``make_pallas_model()``) — the CPU tests' path.  Kernel launches
+    dominate there (a timed dispatch plus its calibration twin per
+    sample), so it defaults to the adaptive sweep planner
+    (``budget=SweepBudget()``; ``budget=None`` forces dense sweeps) and
+    cross-family batch fusion (``fuse=True``).  Fused rounds execute
+    serially, preserving the no-co-running-kernels guarantee of the inline
+    schedule (``max_workers=0``).
+
+    Either way ``runner`` reuses a warmed runner (compiled kernels) across
+    discoveries and must match ``interpret``; persisted samples are never
+    preloaded (a re-measure is a re-measure); topologies are
+    content-addressed in the ``TopologyStore`` and served through
+    ``TopologyService`` exactly like sim/host ones.
     """
     from .probes.pallas_runner import PallasRunner, make_pallas_model
+    from .probes.tpu_runner import TpuRunner
+
+    if runner is not None and bool(runner.interpret) != bool(interpret):
+        raise ValueError(f"runner {type(runner).__name__} does not match "
+                         f"interpret={interpret!r}")
+    if not interpret:
+        if (model is not None or elements or survey or resilience is not None
+                or parallel is not None):
+            raise ValueError("the chip path measures the attached TPU: no "
+                             "model, elements, survey, resilience or pool")
+        runner = runner if runner is not None else TpuRunner()
+        kind = runner.device_kind
+        request = DiscoveryRequest(
+            descriptor=tpu_request_descriptor(kind, n_samples),
+            vendor="Google", model=kind, backend=f"pallas-tpu:{kind}",
+            make_runner=lambda: runner, n_samples=n_samples,
+            device_families=("device_memory_latency",
+                             "device_memory_bandwidth"),
+            max_workers=0, clock_domain="ns", preload_samples=False)
+        return discover(request, store=store, refresh=refresh,
+                        gc_policy=gc_policy)
 
     if budget is _DEFAULT_BUDGET:
         budget = default_sweep_budget()
@@ -911,7 +957,7 @@ def discover_pallas(model=None, n_samples: int = 9,
         vendor=model.vendor, model=model.name,
         backend=f"pallas-interp:{model.name}",
         make_runner=(lambda: runner) if runner is not None
-        else (lambda: PallasRunner(model)),
+        else (lambda: PallasRunner(model, interpret=interpret)),
         n_samples=n_samples, elements=elements,
         device_families=tuple(device_families),
         max_workers=max_workers,

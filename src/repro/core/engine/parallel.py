@@ -38,6 +38,7 @@ context manager) unlinks any stragglers by pool-unique name prefix.
 from __future__ import annotations
 
 import atexit
+import contextlib
 import itertools
 import os
 import pickle
@@ -59,6 +60,9 @@ __all__ = ["ParallelConfig", "ParallelPool", "ParallelRunner", "RunnerSpec",
 #: chaos runner's ``kill_worker_after`` switch) detect in-worker execution
 #: without importing this module.
 POOL_WORKER_ENV = "MT4G_POOL_WORKER"
+
+#: serializes the environment swap around worker starts.
+_SPAWN_ENV_LOCK = threading.Lock()
 
 #: the five batched capabilities the pool shards by rows.
 POOLED_METHODS = ("pchase_batch", "cold_chase_batch", "pchase_many",
@@ -163,6 +167,27 @@ class ParallelConfig:
 # --------------------------------------------------------------------------
 # Worker side
 # --------------------------------------------------------------------------
+@contextlib.contextmanager
+def _cpu_only_children():
+    """Start children with ``JAX_PLATFORMS=cpu`` in their environment.
+
+    A spawned worker inherits the environment at start and may import JAX
+    before any of its own code runs (unpickling its target imports the
+    coordinator's modules).  An accelerator belongs to one process, and the
+    coordinator holds it, so a worker must never reach for it.
+    """
+    with _SPAWN_ENV_LOCK:
+        old = os.environ.get("JAX_PLATFORMS")
+        os.environ["JAX_PLATFORMS"] = "cpu"
+        try:
+            yield
+        finally:
+            if old is None:
+                del os.environ["JAX_PLATFORMS"]
+            else:
+                os.environ["JAX_PLATFORMS"] = old
+
+
 def _worker_main(conn) -> None:
     """Pool worker loop: rebuild runners from specs, serve shard calls.
 
@@ -275,7 +300,8 @@ class ParallelPool:
         parent, child = self._ctx.Pipe()
         proc = self._ctx.Process(target=_worker_main, args=(child,),
                                  daemon=True, name="mt4g-pool-worker")
-        proc.start()
+        with _cpu_only_children():
+            proc.start()
         child.close()
         return _Worker(proc, parent)
 

@@ -3,6 +3,7 @@ from .runners import (HostRunner, ProbeRunner, SimRunner, SpaceInfo,
                       random_cycle, sattolo_cycle)
 from .chaos import ChaosRunner, FaultSchedule
 from .pallas_runner import PallasRunner, make_pallas_model
+from .tpu_runner import TpuRunner
 from .size import SizeResult, find_size
 from .latency import LatencyResult, measure_latency
 from .linesize import (GranularityResult, LineSizeResult,
@@ -17,7 +18,7 @@ from .adjacency import AdjacencyResult, SimPod, find_link_adjacency
 __all__ = [
     "ChaosRunner", "FaultSchedule",
     "HostRunner", "PallasRunner", "ProbeRunner", "SimRunner", "SpaceInfo",
-    "make_pallas_model", "random_cycle", "sattolo_cycle",
+    "TpuRunner", "make_pallas_model", "random_cycle", "sattolo_cycle",
     "SizeResult", "find_size", "LatencyResult", "measure_latency",
     "GranularityResult", "LineSizeResult", "find_fetch_granularity",
     "find_line_size", "snap_pow2",

@@ -79,7 +79,7 @@ def measure_collective(op: str, nbytes: int, axis_size: int,
     if len(devs) >= axis_size > 1:
         from jax.sharding import NamedSharding, PartitionSpec as P
 
-        from repro.compat import make_mesh, shard_map
+        from repro.compat import make_mesh
 
         mesh = make_mesh((axis_size,), ("x",))
         x = jnp.ones((axis_size, max(nbytes // 4 // axis_size, 1)), jnp.float32)
@@ -93,8 +93,8 @@ def measure_collective(op: str, nbytes: int, axis_size: int,
             out_spec = P("x")
         else:
             raise ValueError(f"unsupported live collective '{op}'")
-        mapped = jax.jit(shard_map(body, mesh=mesh, in_specs=P("x"),
-                                   out_specs=out_spec))
+        mapped = jax.jit(jax.shard_map(body, mesh=mesh, in_specs=P("x"),
+                                       out_specs=out_spec))
         mapped(x).block_until_ready()
         best = np.inf
         for _ in range(repeats):

@@ -1,40 +1,40 @@
-"""PallasRunner: the ProbeRunner backend over the real Pallas probe kernels.
+"""PallasRunner: the modeled, interpret-mode ProbeRunner (CPU tests only).
 
-This is the third discovery backend (after Sim and Host) and the one the
-ROADMAP's "wire the engine into a PallasRunner" item asked for: every probe
-request executes the TPU-target kernels from ``repro.kernels`` —
-``pchase_kernel_batch`` for dependent-load chains, ``stream_read_kernel`` /
-``stream_write_kernel`` for bandwidth — in Pallas interpret mode, and the
-*caller times the whole call* (DESIGN.md adaptation note 1: no in-kernel
-clock on TPU).
+The chip path is ``TpuRunner`` (``tpu_runner.py``); this runner exists so
+the probe stack can be exercised end to end on a CPU.  Every probe request
+executes the probe kernels from ``repro.kernels`` —
+``pchase_kernel_batch`` / ``eviction_kernel_batch`` for dependent-load
+chains, ``stream_read_kernel`` / ``stream_write_kernel`` for bandwidth — in
+the Pallas interpreter, and the caller times the whole call (DESIGN.md
+adaptation note 1).
 
-Interpret mode runs on a CPU with no TPU memory system behind it, so the
-hit/miss behavior comes from a configured ground-truth hierarchy (a
-``SimDevice`` model, default ``make_pallas_model``): the modeled level an
-access hits sets the *length of the dependent chain the kernel actually
-executes* — a modeled miss literally serializes more loads, exactly as a
-real miss serializes more cycles — and the reported per-load value comes
-from timing that execution.  Locations of the latency distributions
-therefore track the configured hierarchy (sizes, line size, fetch
-granularity are discoverable and checkable against
+The interpreter has no TPU memory system behind it, so the hit/miss
+behavior comes from a configured ground-truth hierarchy (a ``SimDevice``
+model, default ``make_pallas_model``): the modeled level an access hits
+sets the *length of the dependent chain the kernel actually executes* — a
+modeled miss literally serializes more loads — and the reported per-load
+value comes from timing that execution.  Locations of the latency
+distributions therefore track the configured hierarchy (sizes, line size,
+fetch granularity are discoverable and checkable against
 ``model.ground_truth()``), while the distributions themselves carry real
 end-to-end timing noise, which is what the K-S machinery is built to
-absorb.  On hardware the same runner drops the model and keeps the timing
-loop.
+absorb.  None of this runs on the chip (ROADMAP C3 removes it).
 
 Shared-box drift calibration: the probe workflows compare distributions
 *across* requests (a doubling step against its baseline, an eviction probe
 against hit/miss references), and on a time-shared CPU the interpreter's
 per-step cost drifts by tens of percent between calls — enough to fake a
-regime change.  Every timed execution is therefore normalized by a
-back-to-back **shape-matched calibration chain**: a separate buffer of the
-*same grid shape and the same per-row chain lengths*, executed adjacent in
-time, so a sample is ``modeled_cycles x (request wall / calibration
-wall)``.  Matching the full launch shape — not just the buffer bucket —
-matters because the interpreter charges a per-grid-row overhead: a 100-row
-sweep launch has a very different wall-per-step than a single-row chase,
-and only a calibration with the identical (rows x bucket, steps) profile
-cancels both that overhead and temporal drift.  The result: reported
+regime change.  Every timed execution is therefore normalized by
+**calibration launches of the identical launch** — the same buffers, grid
+shape and per-row chain lengths — spread through the sample loop, so a
+sample is ``modeled_cycles x (request wall / calibration wall)``.  Matching
+the full launch — not just the buffer bucket — matters because the
+interpreter charges a per-grid-row overhead (a 100-row sweep launch has a
+very different wall-per-step than a single-row chase) and because each
+chase step DMAs a 512 B row, so a buffer the host caches have not seen
+runs measurably slower than one just walked: an independent calibration
+buffer read that as a ~25% shift.  Only the identical launch cancels both
+that overhead and temporal drift.  The result: reported
 latencies land in model-cycle units comparable across requests *and
 across launch shapes* — the property the planner's row classification
 (every row judged against one baseline distribution) depends on.
@@ -55,7 +55,7 @@ Implementation notes:
   ``eviction_many`` maps mixed amount/sharing/cu rows onto
   ``eviction_kernel_batch`` — each row executes a real warm-B/probe-A
   two-phase chain (Fig. 3) with both phase lengths as per-row data, and the
-  calibration chain matches the full two-phase launch profile;
+  calibration repeats the full two-phase launch;
 * scratchpad spaces (VMEM/SMEM-like) advertise ``supports_cold=False``:
   end-to-end timing cannot classify individual loads of a cold pass there,
   and the engine registry honors the capability flag by never scheduling
@@ -111,7 +111,7 @@ class PallasRunner:
     """ProbeRunner over ``repro.kernels`` p-chase/stream kernels.
 
     ``base_steps`` is the minimum executed chain length per timed call: the
-    jit dispatch overhead (~20-30 us on this container) must stay small
+    jit dispatch overhead (tens of us on a CPU host) must stay small
     against the kernel's compute time for the wall-clock division to carry
     signal.  ``reps``/``cold_reps`` control how many timed executions back
     each scalar the cold-pass and bandwidth probes report.
@@ -120,20 +120,23 @@ class PallasRunner:
     ELEM_BYTES = 4               # int32 chase indices
     deterministic = False        # samples are real wall-time measurements
 
-    def __init__(self, model: SimDevice | None = None, *,
+    def __init__(self, model: SimDevice | None = None, *, interpret,
                  base_steps: int = 6144, cold_reps: int = 3,
-                 bandwidth_bytes: int = 1 << 21, seed: int = 0,
-                 interpret: bool = True):
+                 bandwidth_bytes: int = 1 << 21, seed: int = 0):
+        # The interpreter is named, never defaulted: ``True`` (Pallas'
+        # interpreter) or ``pltpu.InterpretParams()`` (TPU semantics).
+        if interpret is False:
+            raise ValueError("PallasRunner models its hierarchy and runs only "
+                             "in the interpreter; measure a TPU with "
+                             "TpuRunner (discover_pallas(interpret=False))")
         self.model = model if model is not None else make_pallas_model()
         self.base_steps = int(base_steps)
         self.cold_reps = int(cold_reps)
         self.bandwidth_bytes = int(bandwidth_bytes)
-        self.interpret = bool(interpret)
+        self.interpret = interpret
         self._rng = np.random.default_rng(seed)
         self._perm_cache: dict[int, np.ndarray] = {}
         self._evictor_cache: dict[int, np.ndarray] = {}
-        self._cal_cache: dict[tuple, np.ndarray] = {}  # (shape, tag) -> perms
-        self._cal_cache_cap = 16
         self._warmed: set[tuple] = set()               # launch-shape keys
         self.kernel_calls = 0
         # Eviction-grid utilization (§IV-F/G/H): dispatches vs rows carried.
@@ -196,41 +199,18 @@ class PallasRunner:
             out[i, :n] = self._perm(n)
         return out
 
-    def _cal_perms(self, shape: tuple[int, int], tag: str = "") -> np.ndarray:
-        """Calibration buffers of the given (rows, bucket) launch shape.
-
-        Independent random cycles (never the request's own buffers), small
-        LRU so sweep-sized grids do not accumulate.  The kernel shape is
-        identical to the request's, so the jit cache the request warmed up
-        serves the calibration launch too — no extra warm-up dispatch.
-        ``tag`` separates calibration roles that must use distinct buffers
-        at the same shape (e.g. the eviction kernel's probe vs warm side).
-        """
-        key = (shape, tag)
-        cal = self._cal_cache.pop(key, None)
-        if cal is None:
-            rows, bucket = shape
-            cal = np.stack([random_cycle(bucket, self._rng)
-                            for _ in range(rows)]).astype(np.int32)
-            while len(self._cal_cache) >= self._cal_cache_cap:
-                self._cal_cache.pop(next(iter(self._cal_cache)))
-        self._cal_cache[key] = cal                      # LRU: re-insert last
-        return cal
-
-    def _cal_wall(self, shape: tuple[int, int], steps: np.ndarray) -> float:
-        """ONE wall measurement of the shape-matched calibration chain.
-
-        Same grid shape, same per-row chain lengths, adjacent in time: the
-        request/calibration wall ratio cancels temporal drift AND the
-        interpreter's per-grid-row overhead, leaving model-cycle units
-        comparable across launch shapes (see module docstring).
+    def _cal_wall(self, perms: np.ndarray, steps: np.ndarray) -> float:
+        """ONE wall measurement of the calibration launch: the request's own
+        launch again, adjacent in time, so the request/calibration wall
+        ratio cancels temporal drift, the interpreter's per-grid-row
+        overhead and host-cache warmth alike (see module docstring).
 
         Callers combine multiple calibrations *spread across* their sample
-        loops (min of a before/after pair, median of adjacent pairs):
-        back-to-back calibration repetitions are covered by a single
+        loops (median of a before/middle/after triple, median of adjacent
+        pairs): back-to-back calibration repetitions are covered by a single
         steal-time burst together and would be no more robust than one.
         """
-        return self._run_batch(self._cal_perms(shape), steps)
+        return self._run_batch(perms, steps)
 
     def _maybe_warm(self, perms: np.ndarray, steps: np.ndarray) -> None:
         """Warm-up launch (paper §IV-A) once per (rows, bucket) grid shape.
@@ -322,13 +302,13 @@ class PallasRunner:
         calibrations covers most of the request walls as well, and then
         the ratio stays self-consistent.
         """
-        cal_a = self._cal_wall(perms.shape, steps)
+        cal_a = self._cal_wall(perms, steps)
         half = max(n_samples // 2, 1)
         walls = [self._run_batch(perms, steps) for _ in range(half)]
-        cal_b = self._cal_wall(perms.shape, steps)
+        cal_b = self._cal_wall(perms, steps)
         walls += [self._run_batch(perms, steps)
                   for _ in range(n_samples - half)]
-        cal_c = self._cal_wall(perms.shape, steps)
+        cal_c = self._cal_wall(perms, steps)
         return np.asarray(walls), float(np.median([cal_a, cal_b, cal_c]))
 
     # --------------------------------------------------------- cold chase
@@ -376,7 +356,7 @@ class PallasRunner:
         ratios = []
         for _ in range(self.cold_reps):
             w_req = self._run_batch(perms, steps)
-            ratios.append(w_req / self._cal_wall(perms.shape, steps))
+            ratios.append(w_req / self._cal_wall(perms, steps))
         ratio = float(np.median(ratios))
         return np.stack([ratio * cyc for cyc in cycles_rows])
 
@@ -476,9 +456,8 @@ class PallasRunner:
         the probe's footprint), then the timed probe phase walks the probe
         cycle with a chain length encoding the *modeled* post-warm hit
         level — evicted rows literally serialize more loads.  The
-        calibration chain matches the full two-phase (rows x bucket,
-        warm+probe steps) launch profile, so the wall ratio cancels both
-        drift and the per-row interpreter overhead, exactly as in
+        calibration repeats the same two-phase launch, so the wall ratio
+        cancels drift and the per-row interpreter overhead, exactly as in
         ``_timed_grid``.  Replaces one ``_timed_chase`` dispatch (~12
         launches) per amount/sharing/cu request with a single fused grid.
         """
@@ -499,17 +478,14 @@ class PallasRunner:
         if shape_key not in self._warmed:
             self._run_evict(perms, evictors, warm, probe)
             self._warmed.add(shape_key)
-        cal_args = (self._cal_perms(perms.shape, "evict-probe"),
-                    self._cal_perms(evictors.shape, "evict-warm"),
-                    warm, probe)
-        cal_a = self._run_evict(*cal_args)
+        args = (perms, evictors, warm, probe)
+        cal_a = self._run_evict(*args)
         half = max(int(n_samples) // 2, 1)
-        walls = [self._run_evict(perms, evictors, warm, probe)
-                 for _ in range(half)]
-        cal_b = self._run_evict(*cal_args)
-        walls += [self._run_evict(perms, evictors, warm, probe)
+        walls = [self._run_evict(*args) for _ in range(half)]
+        cal_b = self._run_evict(*args)
+        walls += [self._run_evict(*args)
                   for _ in range(int(n_samples) - half)]
-        cal_c = self._run_evict(*cal_args)
+        cal_c = self._run_evict(*args)
         cal = float(np.median([cal_a, cal_b, cal_c]))
         return lats[:, None] * (np.asarray(walls)[None, :] / cal)
 
@@ -517,9 +493,8 @@ class PallasRunner:
     def bandwidth(self, space, mode="read"):
         """Stream-kernel bandwidth: bytes moved over best-of-reps wall time.
 
-        Interpret-mode numbers characterize this container, not a TPU — the
-        value is that the measurement loop and kernels are the ones a
-        hardware backend reuses unchanged.
+        Interpret-mode numbers characterize the host CPU running the
+        interpreter, not a TPU.
         """
         import jax.numpy as jnp
 
@@ -527,19 +502,22 @@ class PallasRunner:
                                                 stream_write_kernel)
 
         del space  # one DMA path in interpret mode
-        n = self.bandwidth_bytes // 4
-        block = min(64 * KIB, n)
-        n = (n // block) * block
-        x = jnp.arange(n, dtype=jnp.float32)
+        cols = 1024
+        rows = max(self.bandwidth_bytes // (4 * cols), 1)
+        block_rows = min(64, rows)
+        rows = (rows // block_rows) * block_rows
+        x = jnp.arange(rows * cols, dtype=jnp.float32).reshape(rows, cols)
         fn = stream_read_kernel if mode == "read" else stream_write_kernel
-        fn(x, block=block, interpret=self.interpret).block_until_ready()
+        fn(x, block_rows=block_rows,
+           interpret=self.interpret).block_until_ready()
         best = np.inf
         for _ in range(self.cold_reps):
             t0 = time.perf_counter_ns()
-            fn(x, block=block, interpret=self.interpret).block_until_ready()
+            fn(x, block_rows=block_rows,
+               interpret=self.interpret).block_until_ready()
             best = min(best, time.perf_counter_ns() - t0)
             self.kernel_calls += 1
-        moved = n * 4 * (2 if mode == "write" else 1)
+        moved = x.size * 4 * (2 if mode == "write" else 1)
         return moved / (best * 1e-9)
 
     # ------------------------------------------------------------- hooks

@@ -7,12 +7,12 @@ agnostic — the same code drives:
 * ``HostRunner``  — real measurements on this machine's CPU hierarchy using
                     jit-compiled dependent-load chases (the live-hardware
                     sanity check; TPU/GPU-free analogue of paper §V);
-* ``PallasRunner``— the TPU-target kernels in ``repro.kernels``
-                    (``pchase_probe``/``pchase_kernel_batch``,
-                    ``stream_probe``), executed in Pallas interpret mode and
-                    timed end-to-end against a configured ground-truth
-                    hierarchy; lives in ``pallas_runner.py`` and is the
-                    third backend of the unified ``discover()`` driver.
+* ``TpuRunner``  — the probe kernels in ``repro.kernels`` compiled for and
+                    timed on the attached TPU, nothing modeled
+                    (``tpu_runner.py``; ``discover_pallas``' chip path);
+* ``PallasRunner``— the same kernels in the Pallas interpreter, timed
+                    end-to-end against a configured ground-truth hierarchy
+                    (``pallas_runner.py``; CPU tests only).
 
 Per DESIGN.md adaptation note 1, runners without an in-kernel clock time a
 short dependent chain end-to-end and report the distribution across
@@ -236,7 +236,8 @@ class HostRunner:
     Per-load timing at ns resolution is not available from Python, so — per
     DESIGN.md adaptation note 1 — each "sample" is the mean ns/load of a
     jit-compiled dependent-load loop (warm, single cycle), and the probe
-    distribution is built across ``n_samples`` repetitions.
+    distribution is built across ``n_samples`` repetitions.  Its arrays are
+    placed on JAX's CPU device, never on an attached accelerator.
     """
 
     ELEM_BYTES = 4  # int32 chase indices
@@ -247,6 +248,7 @@ class HostRunner:
         import jax  # local import: keep module import cheap
 
         self._jax = jax
+        self._cpu = jax.devices("cpu")[0]
         self.max_bytes = max_bytes
         self.iters = iters
         self.seed = seed
@@ -275,14 +277,12 @@ class HostRunner:
 
     def pchase(self, space, array_bytes, stride, n_samples):
         del space
-        import jax.numpy as jnp
-
         stride_elems = max(stride // self.ELEM_BYTES, 1)
         n = max(array_bytes // self.ELEM_BYTES // stride_elems, 4)
         # Random single cycle over n slots; slot i stands for byte offset
         # i*stride, so the resident footprint matches ``array_bytes``.
         perm_np = sattolo_cycle(n, self._rng)
-        perm = jnp.asarray(perm_np)
+        perm = self._jax.device_put(perm_np, self._cpu)
         run = self._chase_cache.setdefault(0, self._chase_fn())
         iters = max(self.iters, n)
         run(perm, iters).block_until_ready()  # warm-up pass (paper §IV-A)
@@ -347,7 +347,7 @@ class HostRunner:
         import jax.numpy as jnp
 
         n = nbytes // 4
-        x = jnp.arange(n, dtype=jnp.float32)
+        x = jnp.arange(n, dtype=jnp.float32, device=self._cpu)
 
         if mode == "read":
             fn = jax.jit(lambda v: jnp.sum(v))

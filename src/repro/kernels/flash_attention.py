@@ -1,14 +1,14 @@
 """Blockwise (flash) causal GQA attention — Pallas TPU kernel.
 
 TPU-native adaptation (DESIGN.md): online-softmax accumulation in VMEM f32
-scratch, MXU-aligned block shapes (multiples of 128 on the contracting дims),
+scratch, MXU-aligned block shapes (multiples of 128 on the contracting dims),
 grid = (batch, q_heads, q_blocks, kv_blocks) with the kv dimension marked
 "arbitrary" (sequential) so the running (m, l, acc) carry lives across kv
 steps. GQA is expressed in the k/v BlockSpec index maps (q head h reads kv
 head h // group). Causality skips fully-masked kv blocks via pl.when.
 
-Used on real TPUs for train/prefill attention; validated here in interpret
-mode against ref.py's dense oracle across shape/dtype sweeps.
+Compiled for the TPU by default; the CPU tests pass ``interpret=True`` and
+check it against ref.py's dense oracle across shape/dtype sweeps.
 """
 from __future__ import annotations
 
@@ -77,7 +77,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
                                              "interpret"))
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     causal: bool = True, block_q: int = 128,
-                    block_k: int = 128, interpret: bool = True) -> jax.Array:
+                    block_k: int = 128, interpret=False) -> jax.Array:
     """q (B, Hq, Sq, d); k/v (B, Hkv, Sk, d) -> (B, Hq, Sq, d).
 
     Sq % block_q == 0 and Sk % block_k == 0 are required (production path

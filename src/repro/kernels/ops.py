@@ -1,12 +1,12 @@
 """Jit'd public wrappers around the Pallas kernels.
 
-``interpret`` defaults to True in this CPU container; on a TPU fleet the
-launcher flips it to False (the kernels carry explicit BlockSpec tilings and
-MXU-aligned block shapes for that path).
+Every wrapper compiles for the TPU unless the caller names the interpreter:
+``interpret=True`` (Pallas' interpreter) or ``pltpu.InterpretParams()`` (the
+TPU-semantics interpreter, which also checks DMAs and semaphores).  On a
+CPU-only JAX, leaving ``interpret`` out is an error, never a silent switch.
 """
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 
 from .flash_attention import flash_attention
@@ -18,7 +18,7 @@ __all__ = ["mha", "wkv6", "stream_read", "stream_write", "pchase",
            "pchase_batch"]
 
 
-def mha(q, k, v, *, causal=True, block_q=128, block_k=128, interpret=True):
+def mha(q, k, v, *, causal=True, block_q=128, block_k=128, interpret=False):
     """Flash attention over (B, S, H, d) activations (model layout)."""
     qt = jnp.swapaxes(q, 1, 2)
     kt = jnp.swapaxes(k, 1, 2)
@@ -28,25 +28,28 @@ def mha(q, k, v, *, causal=True, block_q=128, block_k=128, interpret=True):
     return jnp.swapaxes(out, 1, 2)
 
 
-def wkv6(r, k, v, w, u, *, chunk=32, interpret=True):
+def wkv6(r, k, v, w, u, *, chunk=32, interpret=False):
     """Chunked WKV6 over (B, T, H, K) activations; returns (y, state)."""
     return wkv6_chunked_kernel(r, k, v, w, u, chunk=chunk,
                                interpret=interpret)
 
 
-def stream_read(x, *, block=64 * 1024, interpret=True):
-    return stream_read_kernel(x, block=block, interpret=interpret)
+def stream_read(x, *, block_rows=1024, interpret=False):
+    """(R, C) -> per-block f32 sums, streamed HBM -> VMEM."""
+    return stream_read_kernel(x, block_rows=block_rows, interpret=interpret)
 
 
-def stream_write(x, *, block=64 * 1024, interpret=True):
-    return stream_write_kernel(x, block=block, interpret=interpret)
+def stream_write(x, *, block_rows=1024, interpret=False):
+    """(R, C) -> x + 1, streamed block by block."""
+    return stream_write_kernel(x, block_rows=block_rows, interpret=interpret)
 
 
-def pchase(perm, *, iters, interpret=True):
+def pchase(perm, *, iters, interpret=False):
+    """One HBM-resident chase of ``iters`` dependent loads."""
     return pchase_kernel(perm, iters=iters, interpret=interpret)
 
 
-def pchase_batch(perms, steps, *, interpret=True):
+def pchase_batch(perms, steps, *, interpret=False):
     """Grid-batched p-chase: (R, N) padded cycles + (R,) per-row chain
     lengths -> (R, 2) [cursor, checksum] rows (one launch per sweep)."""
     return pchase_kernel_batch(perms, jnp.asarray(steps, jnp.int32),

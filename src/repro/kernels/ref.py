@@ -53,8 +53,10 @@ def wkv6_ref(r, k, v, w, u):
     return jnp.moveaxis(ys, 0, 1), state
 
 
-def stream_read_ref(x, block: int):
-    return jnp.sum(x.reshape(-1, block).astype(jnp.float32), axis=1)
+def stream_read_ref(x, block_rows: int):
+    """Per-block f32 sums of a (R, C) array cut into (block_rows, C) blocks."""
+    return jnp.sum(x.reshape(-1, block_rows * x.shape[1]).astype(jnp.float32),
+                   axis=1)
 
 
 def stream_write_ref(x):

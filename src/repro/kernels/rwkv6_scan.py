@@ -72,7 +72,7 @@ def _kernel(r_ref, k_ref, v_ref, w_ref, u_ref, y_ref, state_out_ref, s_scr,
 @functools.partial(jax.jit,
                    static_argnames=("chunk", "interpret"))
 def wkv6_chunked_kernel(r, k, v, w, u, *, chunk: int = 32,
-                        interpret: bool = True):
+                        interpret=False):
     """r,k,w: (B,T,H,K); v: (B,T,H,V); u: (H,K) -> (y (B,T,H,V) f32,
     state (B,H,K,V) f32). Zero initial state (prefill semantics)."""
     b, t, h, kk = r.shape

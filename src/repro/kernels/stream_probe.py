@@ -1,17 +1,17 @@
 """Stream bandwidth probe — Pallas TPU kernel (paper §IV-I, TPU-native).
 
 MT4G's bandwidth benchmark issues wide vector loads from many threads; the
-TPU-native equivalent streams HBM->VMEM tiles across a grid sized to keep
-the DMA engines saturated (DESIGN.md adaptation note 4). Two modes:
+TPU-native equivalent streams HBM->VMEM tiles across a grid, which Pallas
+double-buffers so the DMA engines stay busy (DESIGN.md adaptation note 4).
+Two modes over a 2-D ``(rows, 128·k)`` array with ``(block_rows, 128·k)``
+blocks:
 
-  * read  — per-tile reduction (one f32 out per tile: bytes in, ~0 out);
-  * write — tile copy (bytes in == bytes out), measuring write bandwidth
-            together with read.
+  * read  — one f32 sum per block, written to SMEM (bytes in, ~0 out);
+  * write — block copy ``x + 1`` (bytes in == bytes out).
 
-On hardware the wall clock around ``ops.stream_read/write`` divided into
-bytes gives GB/s; in this container the kernels are validated for
-correctness in interpret mode and the HostRunner measures real bandwidth
-with jitted XLA ops instead.
+The caller times a call around ``block_until_ready`` and divides the bytes
+moved by it.  On the CPU the same kernels run in the interpreter, which
+checks their results and nothing about speed.
 """
 from __future__ import annotations
 
@@ -20,47 +20,58 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["stream_read_kernel", "stream_write_kernel"]
 
 
 def _read_kernel(x_ref, out_ref):
-    out_ref[0] = jnp.sum(x_ref[...].astype(jnp.float32))
+    out_ref[pl.program_id(0)] = jnp.sum(x_ref[...].astype(jnp.float32))
 
 
 def _write_kernel(x_ref, y_ref):
     y_ref[...] = x_ref[...] + jnp.asarray(1, x_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("block", "interpret"))
-def stream_read_kernel(x: jax.Array, *, block: int = 64 * 1024,
-                       interpret: bool = True) -> jax.Array:
-    """x (N,) -> per-block partial sums (N // block,). N % block == 0."""
-    n = x.shape[0]
-    assert n % block == 0
-    grid = (n // block,)
+def _grid(x: jax.Array, block_rows: int) -> tuple[int, pl.BlockSpec, int]:
+    rows, cols = x.shape
+    assert rows % block_rows == 0 and cols % 128 == 0, (x.shape, block_rows)
+    block = pl.BlockSpec((block_rows, cols), lambda i: (i, 0))
+    return rows // block_rows, block, block_rows * cols * x.dtype.itemsize
+
+
+def _params(block_bytes: int) -> pltpu.CompilerParams:
+    # Two buffers per side (Pallas double-buffers), in and out, plus slack.
+    return pltpu.CompilerParams(vmem_limit_bytes=4 * block_bytes + (8 << 20))
+
+
+@functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
+def stream_read_kernel(x: jax.Array, *, block_rows: int = 1024,
+                       interpret=False) -> jax.Array:
+    """x (R, C) -> per-block f32 sums (R // block_rows,); C % 128 == 0."""
+    g, block, block_bytes = _grid(x, block_rows)
     return pl.pallas_call(
         _read_kernel,
-        grid=grid,
-        in_specs=[pl.BlockSpec((block,), lambda i: (i,))],
-        out_specs=pl.BlockSpec((1,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((n // block,), jnp.float32),
+        grid=(g,),
+        in_specs=[block],
+        out_specs=pl.BlockSpec(memory_space=pltpu.SMEM),
+        out_shape=jax.ShapeDtypeStruct((g,), jnp.float32),
+        compiler_params=_params(block_bytes),
         interpret=interpret,
     )(x)
 
 
-@functools.partial(jax.jit, static_argnames=("block", "interpret"))
-def stream_write_kernel(x: jax.Array, *, block: int = 64 * 1024,
-                        interpret: bool = True) -> jax.Array:
-    """x (N,) -> x + 1, streamed tile-by-tile (read+write bytes)."""
-    n = x.shape[0]
-    assert n % block == 0
-    grid = (n // block,)
+@functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
+def stream_write_kernel(x: jax.Array, *, block_rows: int = 1024,
+                        interpret=False) -> jax.Array:
+    """x (R, C) -> x + 1, streamed block by block (read+write bytes)."""
+    g, block, block_bytes = _grid(x, block_rows)
     return pl.pallas_call(
         _write_kernel,
-        grid=grid,
-        in_specs=[pl.BlockSpec((block,), lambda i: (i,))],
-        out_specs=pl.BlockSpec((block,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((n,), x.dtype),
+        grid=(g,),
+        in_specs=[block],
+        out_specs=block,
+        out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
+        compiler_params=_params(block_bytes),
         interpret=interpret,
     )(x)

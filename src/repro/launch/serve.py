@@ -6,16 +6,23 @@ synthetic request stream through the continuous-batching engine.
 """
 import argparse
 
-__all__ = ["main"]
+__all__ = ["main", "run"]
 
 
-def main(argv=None) -> int:
+def run(argv=None) -> dict:
+    """Serve a synthetic request stream; returns what was generated.
+
+    Keys: ``arch``, ``mesh``, ``dtype``, ``vocab_size``, ``outputs`` (one
+    int array of generated token ids per request) and ``wall_s`` (the serve
+    loop, compiles included).
+    """
     import time
 
     import jax
     import numpy as np
 
     from ..checkpoint import Checkpointer
+    from ..compat import make_mesh
     from ..configs import get_config
     from ..models import get_model
     from ..serve import Engine, ServeConfig
@@ -38,7 +45,6 @@ def main(argv=None) -> int:
         cfg = cfg.replace(dtype="float32")
     model = get_model(cfg)
     d, m = (int(x) for x in args.mesh.split("x"))
-    from repro.compat import make_mesh
     mesh = make_mesh((d, m), ("data", "model"))
 
     params, pspecs = model.init(jax.random.PRNGKey(0))
@@ -57,10 +63,22 @@ def main(argv=None) -> int:
             for _ in range(args.requests)]
     t0 = time.perf_counter()
     outs = eng.serve(reqs, max_new=args.max_new)
-    dt = time.perf_counter() - t0
-    toks = sum(o.size for o in outs)
-    print(f"[serve] arch={cfg.name} mesh={args.mesh} requests={len(reqs)} "
-          f"new_tokens={toks} wall={dt:.2f}s throughput={toks/dt:.1f} tok/s")
+    return {"arch": cfg.name, "mesh": args.mesh, "dtype": cfg.dtype,
+            "vocab_size": cfg.vocab_size, "outputs": outs,
+            "wall_s": time.perf_counter() - t0}
+
+
+def main(argv=None) -> int:
+    """CLI entry: serve and print a one-line summary."""
+    from .compile_cache import enable_compile_cache
+
+    enable_compile_cache()
+    res = run(argv)
+    toks = sum(o.size for o in res["outputs"])
+    dt = res["wall_s"]
+    print(f"[serve] arch={res['arch']} mesh={res['mesh']} "
+          f"requests={len(res['outputs'])} new_tokens={toks} wall={dt:.2f}s "
+          f"throughput={toks/dt:.1f} tok/s")
     return 0
 
 

@@ -7,27 +7,13 @@ Builds the mesh, resolves TRAIN_RULES shardings for state and batch, applies
 the activation-sharding context, and runs the fault-tolerant loop
 (checkpointer + supervisor + straggler detector). On a real fleet this is
 the per-process entry point (jax.distributed.initialize is invoked when the
-standard cluster env vars are present); in this container it runs the smoke
-configs on one device.
+standard cluster env vars are present); on a CPU it runs the smoke configs.
 
-Compute/communication overlap: the XLA flags below enable the latency-hiding
-scheduler + async collectives on TPU; they are no-ops on CPU.
+XLA flags for the TPU are not set here: none has been measured on the chip
+yet.
 """
-import os
-
-_OVERLAP_FLAGS = (
-    " --xla_tpu_enable_async_collective_fusion=true"
-    " --xla_tpu_enable_async_collective_fusion_fuse_all_gather=true"
-    " --xla_tpu_overlap_compute_collective_tc=true"
-    " --xla_enable_async_all_gather=true"
-)
-# TPU-only flags: the CPU PJRT plugin hard-fails on unknown flags, so they
-# are applied only when a TPU runtime is actually present/requested.
-if (os.environ.get("REPRO_TPU") or "tpu" in os.environ.get("JAX_PLATFORMS", "")) \
-        and "--xla_tpu" not in os.environ.get("XLA_FLAGS", ""):
-    os.environ["XLA_FLAGS"] = os.environ.get("XLA_FLAGS", "") + _OVERLAP_FLAGS
-
 import argparse
+import os
 
 __all__ = ["main"]
 
@@ -49,6 +35,9 @@ def main(argv=None) -> int:
     ap.add_argument("--resume", action="store_true")
     args = ap.parse_args(argv)
 
+    from .compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     if "COORDINATOR_ADDRESS" in os.environ:       # multi-host fleet
         jax.distributed.initialize()
 

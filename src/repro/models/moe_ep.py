@@ -28,7 +28,6 @@ import jax.ad_checkpoint
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
 
 __all__ = ["moe_ffn_ep"]
 
@@ -131,7 +130,7 @@ def moe_ffn_ep(p, x: jax.Array, cfg, mesh) -> tuple[jax.Array, jax.Array]:
     body = lambda xx, r, a, b, c: _ep_body(
         xx, r, a, b, c, cfg=cfg, dp_axes=dp_axes, ep_axis=ep_axis,
         tp_axis=tp_axis, dsz=int(mesh.shape[ep_axis]))
-    y, probs = shard_map(
+    y, probs = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(batch_entry, None, None),         # x: batch over DP
                   P(None, None),                      # router: replicated

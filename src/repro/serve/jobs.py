@@ -204,10 +204,12 @@ def resolve_discovery(params: dict, store, parallel=None):
         model = make_pallas_model()
         descriptor = pallas_request_descriptor(model, n_samples, elements,
                                                budget, survey=survey)
+        # The remote "pallas" backend is the modeled interpreter path;
+        # chip discovery is not offered over HTTP yet.
         run = lambda: discover_pallas(  # noqa: E731
-            model, n_samples, elements, store=store, refresh=refresh,
-            budget=budget, gc_policy=gc_policy, survey=survey,
-            parallel=parallel)
+            model, n_samples, elements, interpret=True, store=store,
+            refresh=refresh, budget=budget, gc_policy=gc_policy,
+            survey=survey, parallel=parallel)
 
     else:                                                   # host
         from ..core.discover import discover_host

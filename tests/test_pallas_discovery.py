@@ -36,8 +36,9 @@ def discovery(tmp_path_factory):
     for attempt in range(2):
         store = TopologyStore(str(tmp_path_factory.mktemp("pallas-store")))
         model = make_pallas_model()
-        runner = PallasRunner(model)
-        topo, timings = discover_pallas(runner=runner, n_samples=N_SAMPLES,
+        runner = PallasRunner(model, interpret=True)
+        topo, timings = discover_pallas(runner=runner, interpret=True,
+                                        n_samples=N_SAMPLES,
                                         store=store)
         gt = model.ground_truth()
         l1 = topo.find_memory("L1")
@@ -118,7 +119,7 @@ class TestStoreIntegration:
     def test_store_hit_returns_without_kernels(self, discovery):
         calls_before = discovery["runner"].kernel_calls
         topo2, timings2 = discover_pallas(
-            runner=discovery["runner"], n_samples=N_SAMPLES,
+            runner=discovery["runner"], interpret=True, n_samples=N_SAMPLES,
             store=discovery["store"])
         assert discovery["runner"].kernel_calls == calls_before
         assert topo2.to_json() == discovery["topo"].to_json()

@@ -568,7 +568,8 @@ class TestPlannedPallas:
     def test_planner_vs_dense_discrete_identity_shared_rows(self):
         from repro.core.probes import PallasRunner, make_pallas_model
 
-        cached = CachingRunner(PallasRunner(make_pallas_model()))
+        cached = CachingRunner(PallasRunner(make_pallas_model(),
+                                            interpret=True))
         for space, step in (("L1", 32), ("VMEM", 4), ("L2", 32)):
             info = {i.name: i for i in cached.spaces()}[space]
             kw = dict(lo=1024, step=step, n_samples=9,
@@ -602,13 +603,15 @@ class TestPlannedPallas:
         from repro.core.probes import PallasRunner, make_pallas_model
 
         model = make_pallas_model()
-        rd = PallasRunner(model)
-        discover_pallas(runner=rd, n_samples=9, budget=None, fuse=False)
+        rd = PallasRunner(model, interpret=True)
+        discover_pallas(runner=rd, interpret=True, n_samples=9, budget=None,
+                        fuse=False)
         gt = model.ground_truth()
 
         def planned_matches_gt():
-            rp = PallasRunner(model)
-            topo_p, _ = discover_pallas(runner=rp, n_samples=9)
+            rp = PallasRunner(model, interpret=True)
+            topo_p, _ = discover_pallas(runner=rp, interpret=True,
+                                        n_samples=9)
             assert rp.kernel_calls <= 500      # the bench-gated ceiling
             assert rp.kernel_calls < rd.kernel_calls
             for name in ("L1", "L2"):

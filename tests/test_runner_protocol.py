@@ -61,7 +61,8 @@ BACKENDS = {
         cold_unsupported_raises=True,
     ),
     "pallas": dict(
-        make=lambda: PallasRunner(make_pallas_model(), base_steps=2048,
+        make=lambda: PallasRunner(make_pallas_model(), interpret=True,
+                                  base_steps=2048,
                                   cold_reps=2),
         bw_space="L2",
         cold_unsupported_raises=True,

@@ -196,8 +196,7 @@ class TestCompression:
         mesh = Mesh(np.array(jax.devices()[:1]), ("d",))
         g = jnp.linspace(-1, 1, 32)
         e = jnp.zeros(32)
-        from repro.compat import shard_map
-        fn = jax.jit(shard_map(
+        fn = jax.jit(jax.shard_map(
             lambda gg, ee: __import__("repro.train.grad_compress",
                                       fromlist=["compressed_psum"]
                                       ).compressed_psum(gg, ee, "d"),
