@@ -46,6 +46,7 @@ from typing import Callable
 
 import numpy as np
 
+from ..tracing import span
 from .catalog import HardwareSpec
 from .errors import DegradedResult
 from .probes.amount import align_segments, find_amount, find_cu_sharing, find_sharing
@@ -237,8 +238,8 @@ def _store_persist(store, key: str, descriptor: dict, topo: Topology,
                    timings: DiscoveryTimings, cache=None) -> None:
     """Write the topology + sample cache as one locked transaction, so a
     concurrent discovery on the same store cannot interleave a topology
-    from one run with samples from another."""
-    with store.lock():
+    from one run with samples from another (span ``mt4g.store.put``)."""
+    with span("mt4g.store.put"), store.lock():
         store.put(key, topo, meta={"request": descriptor,
                                    "timings": dict(timings.per_family)})
         if cache is not None and len(cache):
@@ -523,7 +524,15 @@ def discover(request: DiscoveryRequest, *, store=None, refresh: bool = False,
     retention sweep: after persisting, the oldest entries beyond the
     policy's ceilings are evicted (topology + samples pairs, under the
     store lock).  Ignored without a ``store``.
+
+    The whole request runs in the profiler span ``mt4g.discover``.
     """
+    with span("mt4g.discover"):
+        return _run_request(request, store, refresh, gc_policy)
+
+
+def _run_request(request: DiscoveryRequest, store, refresh: bool,
+                 gc_policy) -> tuple[Topology, DiscoveryTimings]:
     from .engine import SampleCache, run_probes
     from .engine.cache import CachingRunner
     from .engine.scheduler import run_work_items
@@ -593,7 +602,8 @@ def discover(request: DiscoveryRequest, *, store=None, refresh: bool = False,
             timings.meta["resilience"] = {
                 "retries": eng.retries,
                 "degraded": [d.key for d in eng.degraded]}
-        topo = _assemble_engine_topology(request, runner, eng, timings)
+        with span("mt4g.assemble"):
+            topo = _assemble_engine_topology(request, runner, eng, timings)
     else:
         from .engine.parallel import maybe_parallel_runner
 
@@ -606,7 +616,8 @@ def discover(request: DiscoveryRequest, *, store=None, refresh: bool = False,
                                on_item_done=checkpoint,
                                parallel=request.parallel)
         timings.meta["cache"] = cached.cache.stats()
-        topo = request.assemble(sched, timings)
+        with span("mt4g.assemble"):
+            topo = request.assemble(sched, timings)
 
     if store is not None:
         _store_persist(store, key, request.descriptor, topo, timings,
@@ -789,10 +800,10 @@ def _assemble_engine_topology(request: DiscoveryRequest, runner, eng,
          else topo.compute).append(el)
 
     topo.notes.append(
-        f"discovery wall time: {eng.wall_seconds:.2f}s (engine; "
-        f"per-family cpu { {k: round(v, 2) for k, v in timings.per_family.items()} }; "
+        f"engine: per-family cpu "
+        f"{ {k: round(v, 2) for k, v in timings.per_family.items()} }; "
         f"cache {eng.cache_stats['hits']} hits / "
-        f"{eng.cache_stats['misses']} misses)")
+        f"{eng.cache_stats['misses']} misses")
     return topo
 
 

@@ -34,7 +34,6 @@ class EngineResult:
     infos: list = field(default_factory=list)           # probed spaces, in order
     order: list = field(default_factory=list)           # completion order
     cache_stats: dict = field(default_factory=dict)
-    wall_seconds: float = 0.0
     degraded: list = field(default_factory=list)        # DegradedResult, in order
     retries: int = 0                                    # transient retries spent
 
@@ -168,7 +167,6 @@ def run_probes(runner, n_samples: int = 33, elements: list[str] | None = None,
         infos=infos,
         order=sched.order,
         cache_stats=cached.cache.stats(),
-        wall_seconds=sched.wall_seconds,
         degraded=degraded,
         retries=sched.retries,
     )

@@ -38,7 +38,6 @@ useful as *shares*, not absolute wall seconds.
 from __future__ import annotations
 
 import threading
-import time
 from dataclasses import dataclass, field
 from typing import Callable
 
@@ -341,11 +340,10 @@ def run_fused(items, dispatcher: FusionDispatcher, *, timings=None,
     actually failed — and past the budget it degrades through
     ``on_exhausted`` instead of aborting the whole fused run.
     """
-    from .scheduler import ScheduleResult, check_items
+    from .scheduler import ScheduleResult, check_items, run_item
 
     by_key = check_items(items)
     out = ScheduleResult()
-    t_start = time.perf_counter()
     pending = dict(by_key)
     lock = threading.Lock()
     finished: list[tuple] = []
@@ -357,13 +355,12 @@ def run_fused(items, dispatcher: FusionDispatcher, *, timings=None,
 
     def start(it) -> None:
         def body():
-            t0 = time.perf_counter()
             value = err = None
+            dt = 0.0
             try:
-                value = it.fn(out.results)
+                value, dt = run_item(it, out.results)
             except BaseException as e:  # noqa: BLE001 — re-raised by driver
                 err = e
-            dt = time.perf_counter() - t0
             with lock:
                 finished.append((it, value, err, dt))
             dispatcher.thread_finished()
@@ -426,6 +423,4 @@ def run_fused(items, dispatcher: FusionDispatcher, *, timings=None,
         elif pending:
             raise ValueError("dependency cycle among work items: "
                              f"{sorted(map(str, pending))}")
-
-    out.wall_seconds = time.perf_counter() - t_start
     return out

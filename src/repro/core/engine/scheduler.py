@@ -11,7 +11,9 @@ by request (see ``simulate._KeyedSampler``), so any execution order — and
 any ``max_workers`` — produces identical results.  The per-family wall
 times are accumulated into the same ``DiscoveryTimings`` buckets the legacy
 sequential loop reports (a sum of item durations, matching the paper's
-§V-A per-family accounting).
+§V-A per-family accounting); each item runs in the profiler span
+``mt4g.family.<family>`` whose interval is the one its bucket gets
+(``run_item``, shared with ``fusion.run_fused``).
 """
 from __future__ import annotations
 
@@ -21,9 +23,11 @@ from concurrent.futures import FIRST_COMPLETED, ThreadPoolExecutor, wait
 from dataclasses import dataclass, field
 from typing import Any, Callable, Hashable
 
+from ...tracing import span
 from ..errors import TransientRunnerError
 
-__all__ = ["WorkItem", "ScheduleResult", "run_work_items", "check_items"]
+__all__ = ["WorkItem", "ScheduleResult", "run_work_items", "check_items",
+           "run_item"]
 
 
 @dataclass(frozen=True)
@@ -42,12 +46,11 @@ class WorkItem:
 
 @dataclass
 class ScheduleResult:
-    """Scheduler output: item results, completion order, wall time, and the
+    """Scheduler output: item results, completion order, and the
     fault-tolerance tallies (transient retries spent, items degraded)."""
 
     results: dict = field(default_factory=dict)
     order: list = field(default_factory=list)    # completion order
-    wall_seconds: float = 0.0
     retries: int = 0                             # transient retries spent
     degraded: list = field(default_factory=list)  # keys past the budget
 
@@ -62,6 +65,16 @@ def check_items(items: list[WorkItem]) -> dict:
         if unknown:
             raise ValueError(f"{it.key}: unknown deps {unknown}")
     return by_key
+
+
+def run_item(it: WorkItem, results: dict) -> tuple[Any, float]:
+    """``it.fn(results)`` inside the span ``mt4g.family.<family>``: returns
+    the value and its wall seconds, read just inside the span, so the
+    item's ``DiscoveryTimings`` bucket gets the span's interval."""
+    with span(f"mt4g.family.{it.family}"):
+        t0 = time.perf_counter()
+        value = it.fn(results)
+        return value, time.perf_counter() - t0
 
 
 def run_work_items(items: list[WorkItem], *, max_workers: int | None = None,
@@ -115,7 +128,6 @@ def run_work_items(items: list[WorkItem], *, max_workers: int | None = None,
     by_key = check_items(items)
 
     out = ScheduleResult()
-    t_start = time.perf_counter()
     pending = dict(by_key)
     lock = threading.Lock()
 
@@ -123,9 +135,7 @@ def run_work_items(items: list[WorkItem], *, max_workers: int | None = None,
         return all(d in out.results for d in it.deps)
 
     def run_one(it: WorkItem):
-        t0 = time.perf_counter()
-        value = it.fn(out.results)
-        dt = time.perf_counter() - t0
+        value, dt = run_item(it, out.results)
         if timings is not None and it.family:
             with lock:
                 timings.add(it.family, dt)
@@ -179,7 +189,6 @@ def run_work_items(items: list[WorkItem], *, max_workers: int | None = None,
                 del pending[it.key]
                 if on_item_done is not None:
                     on_item_done(it.key)
-        out.wall_seconds = time.perf_counter() - t_start
         return out
 
     with ThreadPoolExecutor(max_workers=max_workers) as pool:
@@ -203,6 +212,4 @@ def run_work_items(items: list[WorkItem], *, max_workers: int | None = None,
         if pending:
             raise ValueError(
                 f"dependency cycle among work items: {sorted(map(str, pending))}")
-
-    out.wall_seconds = time.perf_counter() - t_start
     return out

@@ -21,6 +21,13 @@ capacities the runtime reports:
 
 The runner refuses to start unless JAX's first device is a TPU: a CPU
 never stands in for the chip.
+
+Profiler spans: ``mt4g.runner.init`` (device lookup, ``get_tpu_info``),
+``mt4g.chase.build`` (a chase buffer built on the host, its puts to the
+device issued), ``mt4g.stream.fill`` (the stream's fill issued) and
+``mt4g.launch``, one per unit of ``kernel_calls``: a kernel's dispatch
+through ``block_until_ready``.  Puts and fill are not waited for: the next
+launch waits for them, as it did before the spans.
 """
 from __future__ import annotations
 
@@ -28,6 +35,7 @@ import time
 
 import numpy as np
 
+from ...tracing import span
 from ..topology import PROVENANCE_API, ComputeElement, MemoryElement
 from .runners import SpaceInfo, random_cycle
 
@@ -74,15 +82,17 @@ class TpuRunner:
         import jax
         from jax.experimental.pallas import tpu as pltpu
 
-        device = jax.devices()[0]
-        if device.platform != "tpu":
-            raise RuntimeError(
-                f"TpuRunner measures a TPU, but JAX's first device is "
-                f"{device.platform!r} ({device.device_kind}); the modeled "
-                f"interpreter path is discover_pallas(interpret=True)")
-        self.device = device
-        self.device_kind = device.device_kind
-        self.info = pltpu.get_tpu_info()
+        with span("mt4g.runner.init"):
+            device = jax.devices()[0]
+            if device.platform != "tpu":
+                raise RuntimeError(
+                    f"TpuRunner measures a TPU, but JAX's first device is "
+                    f"{device.platform!r} ({device.device_kind}); the "
+                    f"modeled interpreter path is "
+                    f"discover_pallas(interpret=True)")
+            self.device = device
+            self.device_kind = device.device_kind
+            self.info = pltpu.get_tpu_info()
         self._rng = np.random.default_rng(0)
         self._chase: dict[tuple[int, int], tuple] = {}
         self._stream = None
@@ -117,11 +127,13 @@ class TpuRunner:
 
             from repro.kernels.pchase_probe import LANES
 
-            buf, slots = strided_cycle(array_bytes, stride, self._rng, LANES)
-            short = max(self.BASE_STEPS, slots)
-            put = lambda a: jax.device_put(a, self.device)  # noqa: E731
-            args = (put(buf), put(np.array([short], np.int32)),
-                    put(np.array([2 * short], np.int32)), short)
+            with span("mt4g.chase.build"):
+                buf, slots = strided_cycle(array_bytes, stride, self._rng,
+                                           LANES)
+                short = max(self.BASE_STEPS, slots)
+                put = lambda a: jax.device_put(a, self.device)  # noqa: E731
+                args = (put(buf), put(np.array([short], np.int32)),
+                        put(np.array([2 * short], np.int32)), short)
             self._chase[key] = args
             self._timed_chase(args[0], args[1])
             self._timed_chase(args[0], args[2])
@@ -130,10 +142,12 @@ class TpuRunner:
     def _timed_chase(self, buf, steps) -> int:
         from repro.kernels.pchase_probe import pchase_kernel_batch
 
-        t0 = time.perf_counter_ns()
-        pchase_kernel_batch(buf, steps).block_until_ready()
+        with span("mt4g.launch"):
+            t0 = time.perf_counter_ns()
+            pchase_kernel_batch(buf, steps).block_until_ready()
+            dt = time.perf_counter_ns() - t0
         self.kernel_calls += 1
-        return time.perf_counter_ns() - t0
+        return dt
 
     def pchase(self, space, array_bytes, stride, n_samples):
         """``n_samples`` per-load latencies (ns) over one footprint."""
@@ -160,16 +174,19 @@ class TpuRunner:
             raise NotImplementedError(f"tpu runner: no space {space!r}")
         if self._stream is None:
             rows = self.STREAM_BYTES // (4 * self.STREAM_COLS)
-            self._stream = jnp.ones((rows, self.STREAM_COLS), jnp.float32,
-                                    device=self.device)
+            with span("mt4g.stream.fill"):
+                self._stream = jnp.ones((rows, self.STREAM_COLS),
+                                        jnp.float32, device=self.device)
         x = self._stream
         fn = stream_read_kernel if mode == "read" else stream_write_kernel
-        fn(x, block_rows=self.STREAM_BLOCK_ROWS).block_until_ready()
+        with span("mt4g.launch"):
+            fn(x, block_rows=self.STREAM_BLOCK_ROWS).block_until_ready()
         best = np.inf
         for _ in range(self.REPS):
-            t0 = time.perf_counter_ns()
-            fn(x, block_rows=self.STREAM_BLOCK_ROWS).block_until_ready()
-            best = min(best, time.perf_counter_ns() - t0)
+            with span("mt4g.launch"):
+                t0 = time.perf_counter_ns()
+                fn(x, block_rows=self.STREAM_BLOCK_ROWS).block_until_ready()
+                best = min(best, time.perf_counter_ns() - t0)
         self.kernel_calls += self.REPS + 1
         moved = x.size * 4 * (2 if mode == "write" else 1)
         return moved / (best * 1e-9)

@@ -25,6 +25,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..models import Runtime
+from ..tracing import span
 
 __all__ = ["ServeConfig", "Engine"]
 
@@ -64,22 +65,32 @@ class Engine:
 
     def generate_batch(self, prompts: np.ndarray, max_new: int,
                        eos_id: int | None = None, seed: int = 0):
-        """One batch of same-length prompts -> (B, <=max_new) generations."""
+        """One batch of same-length prompts -> (B, <=max_new) generations.
+
+        Profiler spans: ``mt4g.serve.prefill`` (the prompt batch and the
+        prefill dispatch); per token ``mt4g.serve.fetch`` (the wait for the
+        step and the logits' copy to the host), ``mt4g.serve.sample`` and
+        ``mt4g.serve.decode`` (the token batch and the decode dispatch)."""
         rng = np.random.default_rng(seed)
-        batch = {"tokens": jnp.asarray(prompts, jnp.int32)}
-        logits, cache = self._prefill(self.params, batch)
+        with span("mt4g.serve.prefill"):
+            batch = {"tokens": jnp.asarray(prompts, jnp.int32)}
+            logits, cache = self._prefill(self.params, batch)
         outs = []
         alive = np.ones(prompts.shape[0], bool)
         for _ in range(max_new):
-            nxt = self._sample(np.asarray(logits, np.float32), rng)
+            with span("mt4g.serve.fetch"):
+                host = np.asarray(logits, np.float32)
+            with span("mt4g.serve.sample"):
+                nxt = self._sample(host, rng)
             outs.append(nxt)
             if eos_id is not None:
                 alive &= nxt != eos_id
                 if not alive.any():
                     break
-            logits, cache = self._decode(
-                self.params, {"tokens": jnp.asarray(nxt[:, None], jnp.int32)},
-                cache)
+            with span("mt4g.serve.decode"):
+                logits, cache = self._decode(
+                    self.params,
+                    {"tokens": jnp.asarray(nxt[:, None], jnp.int32)}, cache)
         return np.stack(outs, axis=1)
 
     def serve(self, requests: list[np.ndarray], max_new: int,
