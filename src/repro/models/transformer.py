@@ -85,7 +85,12 @@ def init(cfg, key: jax.Array):
 # ------------------------------------------------------------------ layers
 def _attn(cfg, p, x, *, cache_kv=None, cur_len=None, pos_offset=0,
           prefix_len=None, rt: Runtime = Runtime()):
-    """One attention sub-block. Returns (out, new_cache_kv)."""
+    """One attention sub-block. Returns (out, kv).
+
+    Without ``cache_kv`` (train / prefill) ``kv`` is the sequence's K/V.
+    With it (decode) the cache is only read: the new tokens attend to the
+    cached positions before ``cur_len`` and to themselves under one
+    softmax, and ``kv`` is the new tokens' K/V for the caller to write."""
     bsz, tq, d = x.shape
     hd = cfg.resolved_head_dim
     h = rms_norm(x, p["ln1"], cfg.norm_eps)
@@ -105,26 +110,31 @@ def _attn(cfg, p, x, *, cache_kv=None, cur_len=None, pos_offset=0,
                         q_chunk=rt.q_chunk)
         new_cache = (k, v)
     else:
-        ck, cv = cache_kv                      # (B, Smax, Hkv, hd)
+        ck, cv = cache_kv                      # (B, Smax, Hkv, hd), read only
         pos = cur_len + jnp.arange(tq)         # decode: tq == 1
         cos, sin = rope_angles(pos, hd, cfg.rope_theta)
         q = apply_rope(q, cos[None], sin[None])
-        k = apply_rope(k, cos[None], sin[None])
-        ck = jax.lax.dynamic_update_slice(ck, k.astype(ck.dtype),
-                                          (0, cur_len, 0, 0))
-        cv = jax.lax.dynamic_update_slice(cv, v.astype(cv.dtype),
-                                          (0, cur_len, 0, 0))
-        smax = ck.shape[1]
-        valid = (jnp.arange(smax) <= cur_len)[None, None, None, None, :]
+        k = apply_rope(k, cos[None], sin[None]).astype(ck.dtype)
+        v = v.astype(cv.dtype)
         hq, hkv = cfg.n_heads, cfg.n_kv_heads
         qg = q.reshape(bsz, tq, hkv, hq // hkv, hd)
-        scores = jnp.einsum("btkgh,bskh->bkgts", qg, ck).astype(jnp.float32)
-        scores = scores / jnp.sqrt(jnp.float32(hd))
-        scores = jnp.where(valid, scores, -1e30)
-        probs = jax.nn.softmax(scores, axis=-1).astype(cv.dtype)
-        out = jnp.einsum("bkgts,bskh->btkgh", probs, cv)
+        f32, scale = jnp.float32, jnp.sqrt(jnp.float32(hd))
+        cached = jnp.einsum("btkgh,bskh->bkgts", qg, ck,
+                            preferred_element_type=f32) / scale
+        cached = jnp.where(jnp.arange(ck.shape[1]) < cur_len, cached, -1e30)
+        fresh = jnp.einsum("btkgh,bukh->bkgtu", qg, k,
+                           preferred_element_type=f32) / scale
+        fresh = jnp.where(jnp.tri(tq, dtype=bool), fresh, -1e30)
+        top = jnp.maximum(cached.max(-1, keepdims=True),
+                          fresh.max(-1, keepdims=True))
+        cached, fresh = jnp.exp(cached - top), jnp.exp(fresh - top)
+        total = cached.sum(-1, keepdims=True) + fresh.sum(-1, keepdims=True)
+        out = (jnp.einsum("bkgts,bskh->btkgh", (cached / total).astype(cv.dtype),
+                          cv, preferred_element_type=f32)
+               + jnp.einsum("bkgtu,bukh->btkgh", (fresh / total).astype(v.dtype),
+                            v, preferred_element_type=f32))
         out = out.reshape(bsz, tq, hq, hd).astype(x.dtype)
-        new_cache = (ck, cv)
+        new_cache = (k, v)
     return jnp.einsum("btnh,nhd->btd", out, p["wo"]).astype(x.dtype), new_cache
 
 
@@ -153,7 +163,9 @@ def _block(cfg, p, x, *, cache_kv=None, cur_len=None, pos_offset=0,
 
 def _run_layers(cfg, layers, x, *, cache=None, cur_len=None, pos_offset=0,
                 prefix_len=None, rt: Runtime = Runtime()):
-    """scan over the stacked layer axis; threads KV caches through."""
+    """scan over the stacked layer axis.  With ``cache`` each layer reads
+    its slice of the stacked K/V and the scan emits only the new tokens'
+    K/V, (L, B, Tq, Hkv, hd), for ``decode_step`` to write."""
 
     def body(carry, scanned):
         h = carry
@@ -163,9 +175,9 @@ def _run_layers(cfg, layers, x, *, cache=None, cur_len=None, pos_offset=0,
                                   prefix_len=prefix_len, rt=rt)
             return h2, probs
         p, (ck, cv) = scanned
-        h2, new_kv, probs = _block(cfg, p, h, cache_kv=(ck, cv),
+        h2, (k, v), probs = _block(cfg, p, h, cache_kv=(ck, cv),
                                    cur_len=cur_len, rt=rt)
-        return h2, (new_kv[0], new_kv[1], probs)
+        return h2, (k, v, probs)
 
     if rt.remat == "save_a2a":
         body = jax.checkpoint(
@@ -177,8 +189,8 @@ def _run_layers(cfg, layers, x, *, cache=None, cur_len=None, pos_offset=0,
     if cache is None:
         x, probs = jax.lax.scan(body, x, layers)
         return x, None, probs
-    x, (ck, cv, probs) = jax.lax.scan(body, x, (layers, cache))
-    return x, (ck, cv), probs
+    x, (k, v, probs) = jax.lax.scan(body, x, (layers, cache))
+    return x, (k, v), probs
 
 
 # ----------------------------------------------------------------- embeds
@@ -290,7 +302,11 @@ def prefill(cfg, params, batch, max_len: int, rt: Runtime = Runtime()):
 
 
 def decode_step(cfg, params, batch, cache, rt: Runtime = Runtime()):
-    """One-token step against a filled KV cache (serve_step for decode_*)."""
+    """One-token step against a filled KV cache (serve_step for decode_*).
+
+    The layers read the cache in place; the new token's K/V is written once
+    after them, at ``len``.  Jit it with the cache donated and that write
+    updates the buffer in place."""
     if cfg.family == "audio":
         toks = batch["tokens"]              # (B, K, 1)
         parts = [params["embed"][kb][toks[:, kb]] for kb in range(cfg.n_codebooks)]
@@ -300,10 +316,13 @@ def decode_step(cfg, params, batch, cache, rt: Runtime = Runtime()):
         if cfg.embed_scale:
             x = x * jnp.sqrt(jnp.float32(cfg.d_model)).astype(x.dtype)
     cur = cache["len"]
-    x, new_kv, _ = _run_layers(cfg, params["layers"], x,
+    x, (k, v), _ = _run_layers(cfg, params["layers"], x,
                                cache=(cache["k"], cache["v"]), cur_len=cur,
                                rt=rt)
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     logits = _logits(cfg, params, x)
-    new_cache = {"k": new_kv[0], "v": new_kv[1], "len": cur + 1}
+    at = (0, 0, cur, 0, 0)
+    new_cache = {"k": jax.lax.dynamic_update_slice(cache["k"], k, at),
+                 "v": jax.lax.dynamic_update_slice(cache["v"], v, at),
+                 "len": cur + 1}
     return logits[:, 0], new_cache

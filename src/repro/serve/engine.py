@@ -51,8 +51,11 @@ class Engine:
         self.cfg = cfg
         self._prefill = jax.jit(
             lambda p, b: model.prefill(p, b, cfg.max_len, cfg.rt))
+        # The cache is donated: the step writes one position into it in
+        # place, and ``generate_batch`` never reads a cache it passed in.
         self._decode = jax.jit(
-            lambda p, b, c: model.decode_step(p, b, c, cfg.rt))
+            lambda p, b, c: model.decode_step(p, b, c, cfg.rt),
+            donate_argnums=(2,))
 
     def _sample(self, logits: np.ndarray, rng: np.random.Generator):
         if self.cfg.temperature <= 0:

@@ -112,6 +112,41 @@ def test_smoke_prefill_decode_consistency(arch):
                                rtol=2e-2, atol=2e-2)
 
 
+@pytest.mark.parametrize("arch", ALL_ARCHS)
+def test_smoke_multistep_decode_consistency(arch):
+    """prefill, then 4 decode steps in a row, each through a jit that donates
+    the cache: every step's logits equal forward's at its position, so the
+    write position moves and the earlier tokens stay in the cache."""
+    cfg = get_config(arch).smoke().replace(dtype="float32")
+    if cfg.family == "moe":
+        cfg = cfg.replace(moe_capacity_factor=16.0)   # as above
+    model = get_model(cfg)
+    params, _ = model.init(jax.random.PRNGKey(4))
+    b, s, steps = 2, 8, 4
+    batch = _smoke_batch(cfg, rng=4, b=b, s=s + steps)
+    whole = {k: v for k, v in batch.items() if k != "targets"}
+    full = batch["tokens"]              # (B, T) or audio's (B, K, T)
+
+    def tokens(lo, hi):
+        return {"tokens": full[..., lo:hi]}
+
+    rt = Runtime(q_chunk=0)
+    max_len = s + steps + (cfg.n_patches if cfg.family == "vlm" else 0)
+    _, cache = jax.jit(
+        lambda p, bb: model.prefill(p, bb, max_len=max_len, rt=rt))(
+            params, {**whole, **tokens(0, s)})
+    decode = jax.jit(lambda p, bb, c: model.decode_step(p, bb, c, rt=rt),
+                     donate_argnums=(2,))
+    logits_full, _ = jax.jit(lambda p, bb: model.forward(p, bb, rt=rt))(
+        params, whole)
+    for j in range(steps):
+        logits, cache = decode(params, tokens(s + j, s + j + 1), cache)
+        np.testing.assert_allclose(
+            np.asarray(logits, np.float32),
+            np.asarray(logits_full[:, s + j], np.float32),
+            rtol=2e-2, atol=2e-2, err_msg=f"{arch}: decode step {j}")
+
+
 def test_loss_decreases_tiny_overfit():
     """A few SGD steps on one batch must reduce the loss (dense family)."""
     cfg = get_config("internlm2-1.8b").smoke().replace(dtype="float32")

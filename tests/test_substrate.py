@@ -235,6 +235,33 @@ class TestServe:
             assert nxt[0] == gen[0, i], f"mismatch at step {i}"
             toks = np.concatenate([toks, nxt[:, None].astype(np.int32)], 1)
 
+    def test_decode_step_donates_its_cache(self):
+        """The decode program writes into the cache it is given: the whole
+        cache aliases the output, and each cache a step is handed is gone
+        after it."""
+        cfg = get_config("internlm2-1.8b").smoke().replace(dtype="float32")
+        model = get_model(cfg)
+        params, _ = model.init(jax.random.PRNGKey(8))
+        eng = Engine(model, params, ServeConfig(max_len=32, slots=2))
+        cache = model.init_cache(2, 32)
+        tokens = {"tokens": jnp.zeros((2, 1), jnp.int32)}
+        memory = eng._decode.lower(params, tokens, cache).compile() \
+            .memory_analysis()
+        kv_bytes = cache["k"].nbytes + cache["v"].nbytes
+        assert memory.alias_size_in_bytes == kv_bytes + cache["len"].nbytes
+
+        handed, decode = [], eng._decode
+
+        def spy(p, b, c):
+            handed.append(c)
+            return decode(p, b, c)
+
+        eng._decode = spy
+        prompts = np.arange(12, dtype=np.int32).reshape(2, 6) % cfg.vocab_size
+        eng.generate_batch(prompts, max_new=3)
+        assert len(handed) == 3
+        assert all(c["k"].is_deleted() and c["v"].is_deleted() for c in handed)
+
     def test_continuous_batching_queue(self):
         cfg = get_config("internlm2-1.8b").smoke().replace(dtype="float32")
         model = get_model(cfg)
