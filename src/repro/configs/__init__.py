@@ -1,15 +1,15 @@
 """Assigned-architecture configs (--arch <id> resolves here)."""
 from .base import SHAPES, ModelConfig, ShapeSpec, shape_for
 
-from . import (codeqwen15_7b, internlm2_1_8b, musicgen_large, paligemma_3b,
-               qwen3_14b, qwen3_32b, qwen3_moe_30b_a3b, qwen3_moe_235b_a22b,
-               rwkv6_3b, zamba2_2_7b)
+from . import (codeqwen15_7b, deepseek_v2_lite, internlm2_1_8b,
+               musicgen_large, paligemma_3b, qwen3_14b, qwen3_32b,
+               qwen3_moe_30b_a3b, qwen3_moe_235b_a22b, rwkv6_3b, zamba2_2_7b)
 
 ARCHS: dict[str, ModelConfig] = {
     m.CONFIG.name: m.CONFIG
     for m in (qwen3_14b, codeqwen15_7b, qwen3_32b, internlm2_1_8b, rwkv6_3b,
               zamba2_2_7b, qwen3_moe_30b_a3b, qwen3_moe_235b_a22b,
-              musicgen_large, paligemma_3b)
+              musicgen_large, paligemma_3b, deepseek_v2_lite)
 }
 
 

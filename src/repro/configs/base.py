@@ -10,14 +10,28 @@ from __future__ import annotations
 import dataclasses
 from dataclasses import dataclass, field
 
-__all__ = ["ModelConfig", "ShapeSpec", "SHAPES", "shape_for"]
+__all__ = ["ModelConfig", "ShapeSpec", "SHAPES", "Yarn", "shape_for"]
+
+
+@dataclass(frozen=True)
+class Yarn:
+    """YaRN rotary scaling (arXiv:2309.00071), in DeepSeek-V2's form:
+    frequencies blended between interpolated and original over a ramp of
+    dimensions, and a softmax scale raised by ``mscale_all_dim``."""
+
+    factor: float
+    original_max_pos: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
 
 
 @dataclass(frozen=True)
 class ModelConfig:
     # identity
     name: str
-    family: str                 # dense | moe | ssm | hybrid | audio | vlm
+    family: str                 # dense | moe | mla | ssm | hybrid | audio | vlm
     source: str = ""            # provenance tag from the assignment table
 
     # transformer backbone
@@ -36,9 +50,22 @@ class ModelConfig:
     embed_scale: bool = False           # gemma multiplies embeds by sqrt(d)
 
     # MoE
-    moe_experts: int = 0
+    moe_experts: int = 0                # routed experts the router scores
     moe_top_k: int = 0
-    moe_capacity_factor: float = 1.25
+    moe_capacity_factor: float = 1.25   # sharded experts, moe_ep slots
+    moe_d_ff: int = 0                   # expert width; 0 -> d_ff
+    moe_shared_d_ff: int = 0            # shared experts as one SwiGLU; 0 = none
+    moe_held: int = 0                   # experts held here; 0 -> all
+    moe_held_first: int = 0             # id of the first expert held here
+    moe_norm_topk: bool = True          # renormalize the top-k weights
+    dense_layers: int = 0               # leading layers with a dense FFN
+
+    # latent attention (mla): q and a latent kv, no query LoRA
+    kv_lora_rank: int = 0
+    qk_nope_dim: int = 0
+    qk_rope_dim: int = 0
+    v_head_dim: int = 0
+    yarn: Yarn | None = None
 
     # SSM / RWKV
     ssm_state: int = 0                  # mamba2 N
@@ -67,6 +94,11 @@ class ModelConfig:
     def resolved_head_dim(self) -> int:
         return self.head_dim or (self.d_model // max(self.n_heads, 1))
 
+    @property
+    def expert_range(self) -> tuple[int, int]:
+        """``(first, count)`` of the routed experts held here."""
+        return self.moe_held_first, self.moe_held or self.moe_experts
+
     def replace(self, **kw) -> "ModelConfig":
         return dataclasses.replace(self, **kw)
 
@@ -84,6 +116,11 @@ class ModelConfig:
         )
         if self.family == "moe":
             kw.update(moe_experts=4, moe_top_k=2)
+        if self.family == "mla":
+            kw.update(n_layers=3, n_kv_heads=4, d_ff=96, moe_experts=8,
+                      moe_top_k=3, moe_d_ff=32, moe_shared_d_ff=64,
+                      moe_held=min(self.moe_held, 4), kv_lora_rank=32,
+                      qk_nope_dim=16, qk_rope_dim=8, v_head_dim=16)
         if self.family in ("ssm", "hybrid"):
             kw.update(ssm_state=8, ssm_head_dim=8, rwkv_decay_lora=8)
         if self.family == "hybrid":
@@ -109,6 +146,18 @@ class ModelConfig:
         if self.family == "moe":
             e = self.moe_top_k if active_only else self.moe_experts
             mlp = 3 * d * ff * e + d * self.moe_experts  # experts + router
+        if self.family == "mla":
+            h, r = self.n_heads, self.kv_lora_rank
+            attn = (d * h * (self.qk_nope_dim + self.qk_rope_dim)
+                    + d * (r + self.qk_rope_dim) + r
+                    + r * h * (self.qk_nope_dim + self.v_head_dim)
+                    + h * self.v_head_dim * d + 2 * d)
+            e = self.moe_top_k if active_only else self.expert_range[1]
+            moe = (3 * d * self.moe_d_ff * e + d * self.moe_experts
+                   + 3 * d * self.moe_shared_d_ff)
+            dl = self.dense_layers
+            return (emb + d + L * attn + dl * 3 * d * ff
+                    + (L - dl) * moe)
         if self.family == "ssm":                          # rwkv6
             att_like = 4 * d * d + 2 * d * self.rwkv_decay_lora * 2
             mlp_like = 2 * d * ff

@@ -1,4 +1,4 @@
-"""Model zoo: 10 assigned architectures behind one functional API."""
+"""Model zoo: 11 assigned architectures behind one functional API."""
 from .model import Model, Runtime, get_model
 
 __all__ = ["Model", "Runtime", "get_model"]

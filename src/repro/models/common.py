@@ -21,7 +21,8 @@ import jax.numpy as jnp
 import numpy as np
 
 __all__ = ["ParamBuilder", "rms_norm", "rope_angles", "apply_rope",
-           "attention", "swiglu", "cross_entropy", "stack_layers", "DTYPES"]
+           "yarn_rope", "attention", "swiglu", "cross_entropy",
+           "stack_layers", "DTYPES"]
 
 DTYPES = {"bfloat16": jnp.bfloat16, "float32": jnp.float32,
           "float16": jnp.float16}
@@ -93,16 +94,64 @@ def rms_norm(x: jax.Array, gamma: jax.Array, eps: float = 1e-6) -> jax.Array:
     return (x * jax.lax.rsqrt(var + eps) * gamma.astype(jnp.float32)).astype(dt)
 
 
-def rope_angles(positions: jax.Array, head_dim: int, theta: float) -> tuple:
-    """NeoX-style rotary angles for given absolute positions (any shape)."""
+def yarn_mscale(factor: float, mscale: float) -> float:
+    return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1 else 1.0
+
+
+def yarn_rope(dim: int, theta: float, yarn) -> dict:
+    """YaRN's rotary frequencies over ``dim`` dimensions, as DeepSeek-V2
+    computes them: each of the ``dim // 2`` frequencies blends the
+    interpolated ``base^(-2i/dim) / factor`` and the original over a
+    linear ramp between the correction dimensions ``low`` and ``high``
+    (the rotations ``beta_fast`` and ``beta_slow`` make over the original
+    context).  ``cos_scale`` multiplies cos and sin; ``attn_scale``, the
+    square of ``mscale_all_dim``'s factor, multiplies the softmax scale."""
+    extra = theta ** (-np.arange(0, dim, 2, dtype=np.float64) / dim)
+    inter = extra / yarn.factor
+
+    def corr(rotations):
+        return (dim * math.log(yarn.original_max_pos
+                               / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(corr(yarn.beta_fast)), 0)
+    high = min(math.ceil(corr(yarn.beta_slow)), dim - 1)
+    ramp = np.clip((np.arange(dim // 2) - low)
+                   / (high - low if high > low else 0.001), 0.0, 1.0)
+    keep = 1.0 - ramp                       # 1: original, 0: interpolated
+    return {"inv_freq": (inter * (1 - keep) + extra * keep).astype(np.float32),
+            "low": low, "high": high,
+            "cos_scale": (yarn_mscale(yarn.factor, yarn.mscale)
+                          / yarn_mscale(yarn.factor, yarn.mscale_all_dim)),
+            "attn_scale": yarn_mscale(yarn.factor, yarn.mscale_all_dim) ** 2}
+
+
+def rope_angles(positions: jax.Array, head_dim: int, theta: float,
+                yarn=None) -> tuple:
+    """NeoX-style rotary angles for given absolute positions (any shape);
+    with ``yarn`` (``configs.Yarn``) YaRN's frequencies, at every
+    position, and its scale on cos and sin."""
     half = head_dim // 2
-    freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    if yarn is None:
+        freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    else:
+        y = yarn_rope(head_dim, theta, yarn)
+        freqs = jnp.asarray(y["inv_freq"])
     ang = positions.astype(jnp.float32)[..., None] * freqs  # (..., half)
-    return jnp.cos(ang), jnp.sin(ang)
+    if yarn is None:
+        return jnp.cos(ang), jnp.sin(ang)
+    return jnp.cos(ang) * y["cos_scale"], jnp.sin(ang) * y["cos_scale"]
 
 
-def apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
-    """x: (..., n_heads, head_dim); cos/sin broadcastable (..., half)."""
+def apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array,
+               interleaved: bool = False) -> jax.Array:
+    """x: (..., n_heads, head_dim); cos/sin broadcastable (..., half).
+
+    ``interleaved``: DeepSeek's pairing, dimensions (0, 1), (2, 3), ...:
+    the dimensions are de-interleaved (evens, then odds) and then rotated
+    by halves; the result stays in that order, as DeepSeek-V2's does."""
+    if interleaved:
+        x = x.reshape(*x.shape[:-1], -1, 2).swapaxes(-1, -2).reshape(x.shape)
     half = x.shape[-1] // 2
     x1, x2 = x[..., :half], x[..., half:]
     cos = cos[..., None, :] if cos.ndim == x.ndim - 1 else cos
@@ -112,7 +161,8 @@ def apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
 
 
 def _attend(q, k, v, mask, scale):
-    """q (B,Tq,Hkv,G,hd), k/v (B,Tk,Hkv,hd), mask (B,1,1,Tq,Tk) or None."""
+    """q (B,Tq,Hkv,G,hd), k (B,Tk,Hkv,hd), v (B,Tk,Hkv,vd), mask
+    (B,1,1,Tq,Tk) or None."""
     scores = jnp.einsum("btkgh,bskh->bkgts", q, k).astype(jnp.float32) * scale
     if mask is not None:
         scores = jnp.where(mask, scores, -1e30)
@@ -123,8 +173,10 @@ def _attend(q, k, v, mask, scale):
 def attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
               causal: bool, q_offset: Any = 0,
               prefix_len: Any = None,
-              q_chunk: int = 0) -> jax.Array:
-    """GQA attention. q (B,Tq,Hq,hd), k/v (B,Tk,Hkv,hd) -> (B,Tq,Hq,hd).
+              q_chunk: int = 0, scale: float | None = None) -> jax.Array:
+    """GQA attention. q/k (B,Tq|Tk,Hq|Hkv,hd), v (B,Tk,Hkv,vd) ->
+    (B,Tq,Hq,vd).  The softmax scale is ``scale``, by default
+    ``1/sqrt(hd)``; the values may be narrower than the queries (MLA).
 
     ``q_offset``: absolute position of q[0] (decode: cache length).
     ``prefix_len``: PaliGemma-style prefix-LM — positions < prefix_len attend
@@ -137,7 +189,9 @@ def attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     tk, hkv = k.shape[1], k.shape[2]
     g = hq // hkv
     qg = q.reshape(b, tq, hkv, g, hd)
-    scale = 1.0 / math.sqrt(hd)
+    vd = v.shape[-1]
+    if scale is None:
+        scale = 1.0 / math.sqrt(hd)
 
     def mask_for(q_pos):
         if not causal:
@@ -160,12 +214,12 @@ def attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
             return carry, out
 
         _, outs = jax.lax.scan(body, 0, (jnp.arange(n_chunks), qs))
-        out = outs.transpose(1, 0, 2, 3, 4, 5).reshape(b, tq, hq, hd)
+        out = outs.transpose(1, 0, 2, 3, 4, 5).reshape(b, tq, hq, vd)
         return out
 
     q_pos = q_offset + jnp.arange(tq)
     out = _attend(qg, k, v, mask_for(q_pos), scale)
-    return out.reshape(b, tq, hq, hd)
+    return out.reshape(b, tq, hq, vd)
 
 
 def swiglu(x: jax.Array, w1: jax.Array, w3: jax.Array, w2: jax.Array) -> jax.Array:

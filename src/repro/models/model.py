@@ -23,8 +23,8 @@ from .transformer import Runtime
 __all__ = ["Model", "Runtime", "get_model"]
 
 _BACKENDS = {
-    "dense": transformer, "moe": transformer, "audio": transformer,
-    "vlm": transformer,
+    "dense": transformer, "moe": transformer, "mla": transformer,
+    "audio": transformer, "vlm": transformer,
     "ssm": rwkv6,
     "hybrid": zamba2,
 }

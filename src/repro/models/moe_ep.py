@@ -119,7 +119,9 @@ def _ep_body(x, router, w1, w3, w2, *, cfg, dp_axes, ep_axis, tp_axis, dsz):
 
 
 def moe_ffn_ep(p, x: jax.Array, cfg, mesh) -> tuple[jax.Array, jax.Array]:
-    """Drop-in for moe.moe_ffn with explicit EP collectives (needs a mesh)."""
+    """moe.moe_ffn's routing with explicit EP collectives (needs a mesh):
+    (out, router probs).  Its send slots have a capacity
+    (``cfg.moe_capacity_factor``), and it keeps no counters."""
     names = mesh.axis_names
     ep_axis = "data" if "data" in names else names[-1]
     tp_axis = "model" if "model" in names else None

@@ -43,12 +43,17 @@ class ServeConfig:
 class Engine:
     """Continuous-batching token server over a fixed slot pool; the
     ``generate`` loop prefills into free slots and decodes all active
-    slots per step with one jitted call."""
+    slots per step with one jitted call.
+
+    ``counters`` sums the counters a model keeps in its cache
+    (``cache["counters"]``: an expert layer's routed pairs and computed
+    rows) over every batch served; a model without them leaves it empty."""
 
     def __init__(self, model, params, cfg: ServeConfig):
         self.model = model
         self.params = params
         self.cfg = cfg
+        self.counters: dict[str, int] = {}
         self._prefill = jax.jit(
             lambda p, b: model.prefill(p, b, cfg.max_len, cfg.rt))
         # The cache is donated: the step writes one position into it in
@@ -73,7 +78,10 @@ class Engine:
         Profiler spans: ``mt4g.serve.prefill`` (the prompt batch and the
         prefill dispatch); per token ``mt4g.serve.fetch`` (the wait for the
         step and the logits' copy to the host), ``mt4g.serve.sample`` and
-        ``mt4g.serve.decode`` (the token batch and the decode dispatch)."""
+        ``mt4g.serve.decode`` (the token batch and the decode dispatch);
+        once after the last token, ``mt4g.serve.counters`` (the wait for
+        the last step and the copy of the model's counters), for a model
+        that keeps counters."""
         rng = np.random.default_rng(seed)
         with span("mt4g.serve.prefill"):
             batch = {"tokens": jnp.asarray(prompts, jnp.int32)}
@@ -94,6 +102,10 @@ class Engine:
                 logits, cache = self._decode(
                     self.params,
                     {"tokens": jnp.asarray(nxt[:, None], jnp.int32)}, cache)
+        if "counters" in cache:
+            with span("mt4g.serve.counters"):
+                for k, v in jax.device_get(cache["counters"]).items():
+                    self.counters[k] = self.counters.get(k, 0) + int(v)
         return np.stack(outs, axis=1)
 
     def serve(self, requests: list[np.ndarray], max_new: int,

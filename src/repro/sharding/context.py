@@ -19,7 +19,7 @@ from jax.sharding import NamedSharding
 
 from .specs import Rules, resolve_spec
 
-__all__ = ["activation_sharding", "constrain", "current_mesh"]
+__all__ = ["activation_sharding", "constrain", "current_mesh", "sharded"]
 
 _CTX: contextvars.ContextVar = contextvars.ContextVar("act_sharding",
                                                       default=None)
@@ -52,3 +52,15 @@ def constrain(x: jax.Array, logical: tuple[str, ...]) -> jax.Array:
         return x
     spec = resolve_spec(tuple(x.shape), logical, rules, mesh)
     return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
+
+
+def sharded(shape: tuple[int, ...], logical: tuple[str, ...]) -> bool:
+    """Whether the active (mesh, rules) split a tensor of ``shape`` with
+    these logical axes over more than one device (False outside the
+    context)."""
+    ctx = _CTX.get()
+    if ctx is None:
+        return False
+    mesh, rules = ctx
+    return any(part is not None
+               for part in resolve_spec(tuple(shape), logical, rules, mesh))

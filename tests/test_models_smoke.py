@@ -64,11 +64,6 @@ def test_smoke_prefill_decode_consistency(arch):
     """prefill(t tokens) then decode_step must equal forward(t+1 tokens) on
     the next-token logits — the KV-cache/state correctness contract."""
     cfg = get_config(arch).smoke().replace(dtype="float32")
-    if cfg.family == "moe":
-        # Isolate cache/state correctness from capacity-drop policy: with a
-        # tiny decode batch vs an 18-token forward, tight capacity drops
-        # DIFFERENT (token,expert) pairs in the two paths by construction.
-        cfg = cfg.replace(moe_capacity_factor=16.0)
     model = get_model(cfg)
     params, _ = model.init(jax.random.PRNGKey(2))
     b, s = 2, 8
@@ -118,8 +113,6 @@ def test_smoke_multistep_decode_consistency(arch):
     the cache: every step's logits equal forward's at its position, so the
     write position moves and the earlier tokens stay in the cache."""
     cfg = get_config(arch).smoke().replace(dtype="float32")
-    if cfg.family == "moe":
-        cfg = cfg.replace(moe_capacity_factor=16.0)   # as above
     model = get_model(cfg)
     params, _ = model.init(jax.random.PRNGKey(4))
     b, s, steps = 2, 8, 4

@@ -1,4 +1,4 @@
-"""shard_map expert-parallel MoE == GSPMD sorted-dispatch MoE (exact)."""
+"""shard_map expert-parallel MoE == the dropless grouped MoE (exact)."""
 import os
 
 if "XLA_FLAGS" not in os.environ:
@@ -14,6 +14,8 @@ from repro.configs import get_config
 from repro.models.common import ParamBuilder
 from repro.models.moe import init_moe, moe_ffn
 from repro.models.moe_ep import moe_ffn_ep
+from repro.sharding import TRAIN_RULES, tree_shardings
+from repro.sharding.context import activation_sharding
 
 
 def _setup(cf=8.0, experts=8, topk=2, seed=0):
@@ -22,7 +24,8 @@ def _setup(cf=8.0, experts=8, topk=2, seed=0):
         moe_capacity_factor=cf)
     b = ParamBuilder(jax.random.PRNGKey(seed), jnp.float32)
     init_moe(b, cfg)
-    p, _ = b.build()
+    p, specs = b.build()
+    _setup.specs = specs
     x = jax.random.normal(jax.random.PRNGKey(seed + 1),
                           (4, 16, cfg.d_model), jnp.float32) * 0.3
     return cfg, p, x
@@ -37,7 +40,7 @@ def test_ep_matches_gspmd(mesh_shape, names):
     if jax.device_count() < int(np.prod(mesh_shape)):
         pytest.skip("not enough devices")
     cfg, p, x = _setup()
-    y0, p0 = moe_ffn(p, x, cfg)
+    y0, p0, _ = moe_ffn(p, x, cfg)
     mesh = make_mesh(mesh_shape, names)
     y1, p1 = jax.jit(lambda pp, xx: moe_ffn_ep(pp, xx, cfg, mesh))(p, x)
     np.testing.assert_allclose(np.asarray(y0), np.asarray(y1),
@@ -71,3 +74,32 @@ def test_ep_capacity_drops_are_bounded():
     mesh = make_mesh((2,), ("data",))
     y1, _ = jax.jit(lambda: moe_ffn_ep(p, x, cfg, mesh))()
     assert np.all(np.isfinite(np.asarray(y1)))
+
+
+@pytest.mark.parametrize("mesh_shape,names", [
+    ((2,), ("data",)),
+    ((2, 2), ("data", "model")),
+])
+def test_sharded_experts_take_the_capacity_dispatch(mesh_shape, names):
+    """Where a mesh splits the experts, the layer takes the capacity
+    dispatch (its rows are the experts' buffers, E*C), so GSPMD computes
+    each expert where its weights live; with room for every pair it
+    equals the dropless layer."""
+    if jax.device_count() < int(np.prod(mesh_shape)):
+        pytest.skip("not enough devices")
+    cfg, p, x = _setup()
+    y0, _, c0 = moe_ffn(p, x, cfg)
+    mesh = make_mesh(mesh_shape, names)
+    p = jax.device_put(p, tree_shardings(p, _setup.specs, TRAIN_RULES, mesh))
+
+    def run(pp, xx):
+        with activation_sharding(mesh, TRAIN_RULES):
+            return moe_ffn(pp, xx, cfg)
+
+    y1, _, c1 = jax.jit(run)(p, x)
+    np.testing.assert_allclose(np.asarray(y0), np.asarray(y1),
+                               rtol=2e-5, atol=2e-5)
+    t, k, e = x.shape[0] * x.shape[1], cfg.moe_top_k, cfg.moe_experts
+    cap = int(t * k / e * cfg.moe_capacity_factor)
+    assert int(c1["moe_pairs"]) == int(c0["moe_pairs"]) == t * k
+    assert int(c0["moe_rows"]) == t * k and int(c1["moe_rows"]) == e * cap
