@@ -13,6 +13,7 @@ Logical axes used across the zoo:
 from __future__ import annotations
 
 import math
+import zlib
 from dataclasses import dataclass
 from typing import Any
 
@@ -38,7 +39,10 @@ class ParamBuilder:
         self.specs: dict[str, Any] = {}
 
     def _next(self, name: str) -> jax.Array:
-        return jax.random.fold_in(self.key, abs(hash(name)) % (2**31 - 1))
+        # crc32, not ``hash``: a str's hash is salted per process, and the
+        # same key must give the same parameters in every process
+        return jax.random.fold_in(self.key, zlib.crc32(name.encode())
+                                  % (2**31 - 1))
 
     def add(self, name: str, shape: tuple[int, ...], axes: tuple[str, ...],
             init: str = "normal", scale: float | None = None) -> None:

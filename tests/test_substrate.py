@@ -205,7 +205,101 @@ class TestCompression:
         np.testing.assert_allclose(np.asarray(out), np.asarray(g), atol=1e-2)
 
 
+def _serial_generation(eng, prompts, max_new, eos_id=None, seed=0):
+    """The serial token loop, as a reference: fetch each step's logits,
+    select on the host (``np.argmax``, or a draw at the engine's
+    temperature), then decode."""
+    rng = np.random.default_rng(seed)
+    logits, cache = eng._prefill(
+        eng.params, {"tokens": jnp.asarray(prompts, jnp.int32)})
+    outs, alive = [], np.ones(prompts.shape[0], bool)
+    for _ in range(max_new):
+        host = np.asarray(logits, np.float32)
+        temp = eng.cfg.temperature
+        if temp <= 0:
+            nxt = np.argmax(host, axis=-1)
+        else:
+            z = host / temp
+            p = np.exp(z - z.max(-1, keepdims=True))
+            p /= p.sum(-1, keepdims=True)
+            nxt = np.array([rng.choice(p.shape[-1], p=row) for row in p])
+        outs.append(nxt)
+        if eos_id is not None:
+            alive &= nxt != eos_id
+            if not alive.any():
+                break
+        logits, cache = eng._decode(
+            eng.params, {"tokens": jnp.asarray(nxt[:, None], jnp.int32)},
+            cache)
+    return np.stack(outs, axis=1)
+
+
+def _smoke_engine(arch, key, **serve):
+    cfg = get_config(arch).smoke().replace(dtype="float32")
+    model = get_model(cfg)
+    params, _ = model.init(jax.random.PRNGKey(key))
+    return Engine(model, params, ServeConfig(**serve)), cfg
+
+
 class TestServe:
+    @pytest.mark.parametrize("arch", ["internlm2-1.8b", "deepseek-v2-lite"])
+    def test_greedy_generation_matches_serial_loop(self, arch):
+        """Selecting on the device and reading one step behind serves the
+        tokens the serial host loop serves."""
+        eng, cfg = _smoke_engine(arch, 12, max_len=32, slots=3)
+        prompts = (np.arange(18, dtype=np.int32).reshape(3, 6) * 7) \
+            % cfg.vocab_size
+        got = eng.generate_batch(prompts, max_new=6)
+        np.testing.assert_array_equal(
+            got, _serial_generation(eng, prompts, 6))
+        assert got.shape == (3, 6) and eng.steps == 6
+
+    def test_sampling_at_a_temperature_matches_serial_loop(self):
+        eng, cfg = _smoke_engine("internlm2-1.8b", 13, max_len=32, slots=2,
+                                 temperature=0.7)
+        prompts = np.arange(12, dtype=np.int32).reshape(2, 6) % cfg.vocab_size
+        got = eng.generate_batch(prompts, max_new=5, seed=3)
+        np.testing.assert_array_equal(
+            got, _serial_generation(eng, prompts, 5, seed=3))
+
+    def test_greedy_tokens_stay_on_the_device(self):
+        """Every token batch the decode program is handed is the device
+        array ``_sample`` returned; ``_sample`` runs once per token."""
+        eng, cfg = _smoke_engine("internlm2-1.8b", 14, max_len=32, slots=2)
+        sampled, handed = [], []
+        sample, decode = eng._sample, eng._decode
+
+        def spy_sample(logits, rng):
+            assert isinstance(logits, jax.Array)
+            sampled.append(sample(logits, rng))
+            return sampled[-1]
+
+        def spy_decode(p, b, c):
+            handed.append(b["tokens"])
+            return decode(p, b, c)
+
+        eng._sample, eng._decode = spy_sample, spy_decode
+        prompts = np.arange(12, dtype=np.int32).reshape(2, 6) % cfg.vocab_size
+        eng.generate_batch(prompts, max_new=5)
+        assert len(sampled) == 5 and len(handed) == 5
+        assert all(isinstance(t, jax.Array) for t in handed)
+        assert all(t is s for t, s in zip(handed, sampled))
+
+    def test_eos_cuts_where_the_serial_loop_cuts(self):
+        """One slot emits the stop id at step 1 and the other at step 4:
+        the batch stops after step 4, as the serial loop does, although
+        the device ran a step ahead."""
+        eng, cfg = _smoke_engine("internlm2-1.8b", 1, max_len=32, slots=2)
+        prompts = np.arange(12, dtype=np.int32).reshape(2, 6) % cfg.vocab_size
+        full = _serial_generation(eng, prompts, 8)
+        eos = full[0, 1]
+        assert eos not in full[0, :1] and eos not in full[1, :4]
+        assert full[1, 4] == eos
+        want = _serial_generation(eng, prompts, 8, eos_id=eos)
+        got = eng.generate_batch(prompts, max_new=8, eos_id=eos)
+        assert want.shape == (2, 5)
+        np.testing.assert_array_equal(got, want)
+
     def test_greedy_generation_deterministic(self):
         cfg = get_config("internlm2-1.8b").smoke().replace(dtype="float32")
         model = get_model(cfg)

@@ -152,19 +152,27 @@ def test_generate_batch_spans(tmp_path):
     eng = Engine(model, params, ServeConfig(max_len=32, slots=2))
     prompts = np.arange(12, dtype=np.int32).reshape(2, 6) % cfg.vocab_size
     want = eng.generate_batch(prompts, max_new=4)       # compiles
+    before = eng.steps
     with jax.profiler.trace(str(tmp_path)):
         got = eng.generate_batch(prompts, max_new=4)
     np.testing.assert_array_equal(got, want)
+    assert eng.steps - before == 4
     spans = _spans(tmp_path)
     counts = {n: len(_named(spans, n)) for n in (
         "mt4g.serve.prefill", "mt4g.serve.fetch", "mt4g.serve.sample",
         "mt4g.serve.decode")}
     assert counts == {"mt4g.serve.prefill": 1, "mt4g.serve.fetch": 4,
                       "mt4g.serve.sample": 4, "mt4g.serve.decode": 4}
-    # per token: fetch, then sample, then decode, on one thread
+    # on one thread: the first token is selected and read before the
+    # first decode; then each step dispatches its decode and the next
+    # selection before it reads the token it decoded, one step behind;
+    # the last decode's logits go unsampled
     steps = [s[0] for s in spans if s[0] != "mt4g.serve.prefill"]
-    assert steps == ["mt4g.serve.fetch", "mt4g.serve.sample",
-                     "mt4g.serve.decode"] * 4
+    decode, sample, fetch = ("mt4g.serve.decode", "mt4g.serve.sample",
+                             "mt4g.serve.fetch")
+    assert steps == [sample, fetch, decode, sample,
+                     decode, sample, fetch, decode, sample, fetch,
+                     decode, fetch]
 
 
 def test_run_item_times_its_span(tmp_path):
