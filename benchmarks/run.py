@@ -156,7 +156,7 @@ def bench_engine_speedup() -> None:
             legacy_best = min(legacy_best, time.perf_counter() - t0)
             t0 = time.perf_counter()
             topo_e, _ = discover_sim(make(seed=48), n_samples=17,
-                                     max_workers=0, budget=SweepBudget())
+                                     budget=SweepBudget())
             engine_best = min(engine_best, time.perf_counter() - t0)
         legacy_s += legacy_best
         engine_s += engine_best
@@ -182,8 +182,8 @@ def bench_adaptive_speedup() -> None:
     identical = True
     t0 = time.perf_counter()
     for make in (make_h100_like, make_mi210_like):
-        topo_d, td = discover_sim(make(seed=48), n_samples=17, max_workers=0)
-        topo_p, tp = discover_sim(make(seed=48), n_samples=17, max_workers=0,
+        topo_d, td = discover_sim(make(seed=48), n_samples=17)
+        topo_p, tp = discover_sim(make(seed=48), n_samples=17,
                                   budget=SweepBudget())
         rows_dense += td.probe_rows
         rows_planned += tp.probe_rows

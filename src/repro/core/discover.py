@@ -23,10 +23,11 @@ backend-specific:
   with ``interpret=True`` the ``PallasRunner`` runs them in the
   interpreter against a configured ground-truth hierarchy (CPU tests).
 
-A fourth path, ``discover_sim_legacy`` (also ``discover_sim(engine=False)``)
-keeps the paper-faithful sequential loop: one probe at a time, exactly as
-the paper's tool runs them — the reference implementation and the baseline
-of the ``engine_speedup`` benchmark.
+A fourth path, ``discover_sim_legacy``, keeps the paper-faithful
+sequential loop: one probe at a time, exactly as the paper's tool runs
+them — the reference implementation the engine is compared against, and
+the baseline of the ``engine_speedup`` benchmark.  It reads and writes no
+store.
 
 Engine and legacy results are identical for simulated devices because those
 runners key every sample stream by the request itself
@@ -132,8 +133,8 @@ def sim_request_descriptor(device, n_samples: int,
                            elements: list[str] | None, budget=None,
                            survey: bool = False, resilience=None) -> dict:
     """Everything that determines a ``discover_sim`` result — and nothing
-    that does not.  Worker count, engine-vs-legacy, batching, and fusion
-    are excluded: request-keyed sample streams make them result-invisible
+    that does not.  Engine-vs-legacy, batching, pooling and fusion are
+    excluded: request-keyed sample streams make them result-invisible
     up to the ``topology_equivalent`` contract (discrete attributes exact,
     floats within rel-tol — and bit-identical in practice on the validation
     devices), so the key addresses that equivalence class.  A ``budget``
@@ -235,14 +236,14 @@ def _store_lookup(store, descriptor: dict):
 
 
 def _store_persist(store, key: str, descriptor: dict, topo: Topology,
-                   timings: DiscoveryTimings, cache=None) -> None:
+                   timings: DiscoveryTimings, cache) -> None:
     """Write the topology + sample cache as one locked transaction, so a
     concurrent discovery on the same store cannot interleave a topology
     from one run with samples from another (span ``mt4g.store.put``)."""
     with span("mt4g.store.put"), store.lock():
         store.put(key, topo, meta={"request": descriptor,
                                    "timings": dict(timings.per_family)})
-        if cache is not None and len(cache):
+        if len(cache):
             store.put_samples(key, cache.snapshot())
 
 
@@ -469,7 +470,6 @@ class DiscoveryRequest:
     n_samples: int = 33
     elements: list[str] | None = None
     device_families: tuple[str, ...] = ()
-    max_workers: int | None = None
     clock_domain: str = "cycles"
     cu_space: str = "sL1d"            # the space CU-sharing groups attach to
     # Preloading persisted samples re-serves *recorded* probe rows.  That is
@@ -592,7 +592,7 @@ def _run_request(request: DiscoveryRequest, store, refresh: bool,
         eng = run_probes(runner, n_samples=request.n_samples,
                          elements=request.elements,
                          device_families=request.device_families,
-                         max_workers=request.max_workers, timings=timings,
+                         timings=timings,
                          cache=cache, budget=request.budget,
                          fuse=request.fuse, resilience=request.resilience,
                          checkpoint=checkpoint, parallel=request.parallel)
@@ -609,12 +609,9 @@ def _run_request(request: DiscoveryRequest, store, refresh: bool,
 
         cached = CachingRunner(
             maybe_parallel_runner(runner, request.parallel), cache=cache)
-        sched = run_work_items(request.plan(cached),
-                               max_workers=request.max_workers,
-                               timings=timings,
+        sched = run_work_items(request.plan(cached), timings=timings,
                                resilience=request.resilience,
-                               on_item_done=checkpoint,
-                               parallel=request.parallel)
+                               on_item_done=checkpoint)
         timings.meta["cache"] = cached.cache.stats()
         with span("mt4g.assemble"):
             topo = request.assemble(sched, timings)
@@ -812,16 +809,13 @@ def _assemble_engine_topology(request: DiscoveryRequest, runner, eng,
 # --------------------------------------------------------------------------
 def discover_sim(device, n_samples: int = 33,
                  elements: list[str] | None = None, *,
-                 engine: bool = True, max_workers: int | None = None,
                  store=None, refresh: bool = False, budget=None,
                  fuse: bool = False, gc_policy=None, survey: bool = False,
                  resilience=None, parallel=None,
                  ) -> tuple[Topology, DiscoveryTimings]:
-    """Full MT4G-style discovery of a simulated device.
-
-    ``engine=True`` (default) routes through the unified driver and the
-    batched probe engine; ``engine=False`` runs the legacy sequential loop.
-    Both produce the same topology for a fixed device seed.  ``store`` /
+    """Full MT4G-style discovery of a simulated device, through the unified
+    driver and the batched probe engine.  It produces the same topology as
+    ``discover_sim_legacy`` for a fixed device seed.  ``store`` /
     ``refresh`` / ``gc_policy`` behave as documented on ``discover()``.
 
     ``budget`` (a ``SweepBudget``) turns on the adaptive sweep planner —
@@ -850,21 +844,6 @@ def discover_sim(device, n_samples: int = 33,
     descriptor = sim_request_descriptor(device, n_samples, elements, budget,
                                         survey=survey, resilience=resilience)
 
-    if not engine:
-        key = None
-        if store is not None:
-            if not refresh:
-                key, hit = _store_lookup(store, descriptor)
-                if hit is not None:
-                    return hit
-            else:
-                from .engine.store import request_key
-                key = request_key(descriptor)
-        topo, timings = discover_sim_legacy(device, n_samples, elements)
-        if store is not None:
-            _store_persist(store, key, descriptor, topo, timings)
-        return topo, timings
-
     device_families = ["sharing", "device_memory_latency",
                        "device_memory_bandwidth"]
     if device.cu_share_groups and (not elements or "sL1d" in elements):
@@ -877,7 +856,6 @@ def discover_sim(device, n_samples: int = 33,
         make_runner=lambda: SimRunner(device),
         n_samples=n_samples, elements=elements,
         device_families=tuple(device_families),
-        max_workers=max_workers,
         preload_samples=True,           # request-keyed streams: sound
         budget=budget, fuse=fuse, survey=survey, resilience=resilience,
         parallel=parallel,
@@ -892,7 +870,6 @@ def discover_sim(device, n_samples: int = 33,
 def discover_pallas(model=None, n_samples: int = 9,
                     elements: list[str] | None = None, *,
                     interpret=False, runner=None,
-                    max_workers: int | None = 0,
                     store=None, refresh: bool = False,
                     budget=_DEFAULT_BUDGET, fuse: bool = True,
                     gc_policy=None, survey: bool = False, resilience=None,
@@ -918,7 +895,7 @@ def discover_pallas(model=None, n_samples: int = 9,
     (``budget=SweepBudget()``; ``budget=None`` forces dense sweeps) and
     cross-family batch fusion (``fuse=True``).  Fused rounds execute
     serially, preserving the no-co-running-kernels guarantee of the inline
-    schedule (``max_workers=0``).
+    schedule.
 
     Either way ``runner`` reuses a warmed runner (compiled kernels) across
     discoveries and must match ``interpret``; persisted samples are never
@@ -945,7 +922,7 @@ def discover_pallas(model=None, n_samples: int = 9,
             make_runner=lambda: runner, n_samples=n_samples,
             device_families=("device_memory_latency",
                              "device_memory_bandwidth"),
-            max_workers=0, clock_domain="ns", preload_samples=False)
+            clock_domain="ns", preload_samples=False)
         return discover(request, store=store, refresh=refresh,
                         gc_policy=gc_policy)
 
@@ -971,7 +948,6 @@ def discover_pallas(model=None, n_samples: int = 9,
         else (lambda: PallasRunner(model, interpret=interpret)),
         n_samples=n_samples, elements=elements,
         device_families=tuple(device_families),
-        max_workers=max_workers,
         clock_domain="interp-cycles",   # chain-length units, timed end-to-end
         preload_samples=False,          # real measurements: always re-measure
         budget=budget, fuse=fuse, survey=survey, resilience=resilience,
@@ -994,13 +970,13 @@ def discover_host(max_bytes: int = 128 * 1024**2, n_samples: int = 9,
     """Live discovery of this machine's CPU hierarchy (real measurements).
 
     The host hierarchy has one probeable space, so instead of the registry
-    it hands the unified driver a small custom work-item plan (size ∥
-    latencies ∥ bandwidths, all independent on real hardware) — sharing the
-    same store, caching, scheduling, and timing machinery as the other
-    backends.  ``store`` works as in ``discover()`` — host measurements are
-    slow and real, so serving a prior run of the same request from the
-    store is the common production path; ``refresh=True`` forces a
-    re-measure.
+    it hands the unified driver a small custom work-item plan (size,
+    latencies, bandwidths: independent items, run one at a time so no probe
+    perturbs another's timing) — sharing the same store, caching,
+    scheduling, and timing machinery as the other backends.  ``store``
+    works as in ``discover()`` — host measurements are slow and real, so
+    serving a prior run of the same request from the store is the common
+    production path; ``refresh=True`` forces a re-measure.
     """
     from .engine import WorkItem
 
@@ -1054,10 +1030,6 @@ def discover_host(max_bytes: int = 128 * 1024**2, n_samples: int = 9,
         make_runner=lambda: HostRunner(
             max_bytes=max_bytes, iters=1 << 14 if quick else 1 << 16),
         n_samples=n_samples,
-        # Real measurements are perturbed by co-running probes: keep the
-        # host schedule serial so timings stay trustworthy — the engine's
-        # value here is the shared orchestration, not parallelism.
-        max_workers=1,
         preload_samples=False,          # real measurements: always re-measure
         plan=plan, assemble=assemble, parallel=parallel,
     )

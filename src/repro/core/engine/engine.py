@@ -2,7 +2,7 @@
 
 ``run_probes`` is the engine entry point: it wraps a ``ProbeRunner`` in the
 keyed sample cache, expands the probe registry into (space × family) work
-items with their dependency edges, runs them on the concurrent scheduler,
+items with their dependency edges, runs them on the scheduler,
 and returns the raw probe results plus per-family timings and cache/order
 diagnostics.  The unified ``discover.discover(request)`` core drives this
 function for every backend (the ``discover_sim``/``discover_host``/
@@ -40,8 +40,7 @@ class EngineResult:
 
 def run_probes(runner, n_samples: int = 33, elements: list[str] | None = None,
                *, device_families: tuple[str, ...] = (),
-               max_workers: int | None = None, timings=None,
-               cache: SampleCache | None = None, budget=None,
+               timings=None, cache: SampleCache | None = None, budget=None,
                fuse: bool = False, resilience=None,
                checkpoint=None, parallel=None) -> EngineResult:
     """Run the full registry against ``runner`` through the engine.
@@ -54,8 +53,7 @@ def run_probes(runner, n_samples: int = 33, elements: list[str] | None = None,
     the adaptive coarse-to-fine planner — identical discrete attributes,
     ~4-8x fewer probed rows.  ``fuse=True`` runs the schedule through the
     cross-family fusion dispatcher: concurrently ready items coalesce their
-    probe rounds into single ``pchase_many``/``cold_chase_many`` dispatches
-    (``max_workers`` is ignored in fused mode).
+    probe rounds into single ``pchase_many``/``cold_chase_many`` dispatches.
 
     ``resilience`` (an ``errors.Resilience``) turns on per-item transient
     retry with graceful degradation: an item that exhausts its retry
@@ -153,10 +151,10 @@ def run_probes(runner, n_samples: int = 33, elements: list[str] | None = None,
         items.append(WorkItem(key=(DEVICE_KEY, spec.family), fn=fn,
                               deps=deps, family=bucket))
 
-    sched = run_work_items(items, max_workers=max_workers, timings=timings,
-                           fuser=dispatcher, resilience=resilience,
+    sched = run_work_items(items, timings=timings, fuser=dispatcher,
+                           resilience=resilience,
                            on_exhausted=on_exhausted if resilience else None,
-                           on_item_done=checkpoint, parallel=parallel)
+                           on_item_done=checkpoint)
 
     device_results = {fam: sched.results[(DEVICE_KEY, fam)]
                       for fam in device_families

@@ -22,8 +22,8 @@ import jax.numpy as jnp
 import numpy as np
 
 __all__ = ["ParamBuilder", "rms_norm", "rope_angles", "apply_rope",
-           "yarn_rope", "attention", "swiglu", "cross_entropy",
-           "stack_layers", "DTYPES"]
+           "yarn_rope", "attention", "write_rows", "swiglu",
+           "cross_entropy", "stack_layers", "DTYPES"]
 
 DTYPES = {"bfloat16": jnp.bfloat16, "float32": jnp.float32,
           "float16": jnp.float16}
@@ -175,14 +175,12 @@ def _attend(q, k, v, mask, scale):
 
 
 def attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
-              causal: bool, q_offset: Any = 0,
-              prefix_len: Any = None,
+              causal: bool, prefix_len: Any = None,
               q_chunk: int = 0, scale: float | None = None) -> jax.Array:
     """GQA attention. q/k (B,Tq|Tk,Hq|Hkv,hd), v (B,Tk,Hkv,vd) ->
     (B,Tq,Hq,vd).  The softmax scale is ``scale``, by default
     ``1/sqrt(hd)``; the values may be narrower than the queries (MLA).
 
-    ``q_offset``: absolute position of q[0] (decode: cache length).
     ``prefix_len``: PaliGemma-style prefix-LM — positions < prefix_len attend
     bidirectionally, the rest causally.
     ``q_chunk``: if >0 and Tq >= 2*q_chunk, scan over query chunks so the
@@ -213,7 +211,7 @@ def attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
 
         def body(carry, args):
             i, qc = args
-            q_pos = q_offset + i * q_chunk + jnp.arange(q_chunk)
+            q_pos = i * q_chunk + jnp.arange(q_chunk)
             out = _attend(qc, k, v, mask_for(q_pos), scale)
             return carry, out
 
@@ -221,9 +219,16 @@ def attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
         out = outs.transpose(1, 0, 2, 3, 4, 5).reshape(b, tq, hq, vd)
         return out
 
-    q_pos = q_offset + jnp.arange(tq)
-    out = _attend(qg, k, v, mask_for(q_pos), scale)
+    out = _attend(qg, k, v, mask_for(jnp.arange(tq)), scale)
     return out.reshape(b, tq, hq, vd)
+
+
+def write_rows(cache, new, cur):
+    """A stack's cache with the new tokens' entries (L, B, T, ...) written
+    in place at position ``cur`` of each leaf's sequence axis (axis 2)."""
+    return jax.tree.map(
+        lambda a, u: jax.lax.dynamic_update_slice(
+            a, u, (0, 0, cur) + (0,) * (a.ndim - 3)), cache, new)
 
 
 def swiglu(x: jax.Array, w1: jax.Array, w3: jax.Array, w2: jax.Array) -> jax.Array:

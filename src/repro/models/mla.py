@@ -15,30 +15,36 @@ head: ``{"c": (..., rank), "k_pe": (..., rope)}``.  Two arrays and not one
 row of ``rank + rope``: a 576-wide row would be laid out with the
 position minor, and writing one position then touches every tile.
 
-Two paths compute the same attention:
+The module has ``gqa.py``'s interface (``init_attn``, ``init_cache``,
+``CACHE_AXES``, ``write``, ``attend``, ``attend_cached``), so that
+``transformer.py`` treats both alike.  Its two forms compute the same
+attention two ways:
 
-* ``expanded`` (prefill, training): K and V per head come from the
-  latent through ``W_kb``/``W_vb`` and go through ``common.attention``;
-  the work grows with the heads' widths, which pays at long queries.
-* ``absorbed`` (decode): ``q_nope`` is folded into latent space through
-  ``W_kb`` (``q_nope . (c W_kb) = (W_kb q_nope) . c``); the scores are
-  taken against the cached rows themselves, the weighted latent leaves
-  through ``W_vb``; only the cache's rows are read.
+* ``attend`` — expanded (prefill, training): K and V per head come from
+  the latent through ``W_kb``/``W_vb`` and go through
+  ``common.attention``; the work grows with the heads' widths, which pays
+  at long queries.
+* ``attend_cached`` — absorbed (decode): ``q_nope`` is folded into latent
+  space through ``W_kb`` (``q_nope . (c W_kb) = (W_kb q_nope) . c``); the
+  scores are taken against the cached rows themselves, the weighted
+  latent leaves through ``W_vb``; only the cache's rows are read.
 """
 from __future__ import annotations
 
 import math
 
-import jax
 import jax.numpy as jnp
 
-from .common import apply_rope, attention, rms_norm, rope_angles, yarn_rope
+from .common import (apply_rope, attention, rms_norm, rope_angles,
+                     write_rows, yarn_rope)
 
-__all__ = ["init_attn", "expanded", "absorbed", "softmax_scale",
+__all__ = ["init_attn", "attend", "attend_cached", "softmax_scale",
            "init_cache", "CACHE_AXES", "write"]
 
 CACHE_AXES = {"c": ("layers", "batch", "kv_seq", "kv_latent"),
               "k_pe": ("layers", "batch", "kv_seq", "head_dim")}
+
+write = write_rows
 
 
 def init_attn(b, cfg) -> None:
@@ -58,14 +64,6 @@ def init_cache(cfg, n_layers: int, batch: int, max_len: int, dtype):
     widths = {"c": cfg.kv_lora_rank, "k_pe": cfg.qk_rope_dim}
     return {k: jnp.zeros((n_layers, batch, max_len, w), dtype)
             for k, w in widths.items()}
-
-
-def write(cache, new, cur):
-    """A stack's cache with the new tokens' entries (L, B, T, .) written
-    in place at position ``cur``."""
-    return jax.tree.map(
-        lambda a, u: jax.lax.dynamic_update_slice(a, u, (0, 0, cur, 0)),
-        cache, new)
 
 
 def softmax_scale(cfg) -> float:
@@ -91,24 +89,25 @@ def _project(cfg, p, h, pos):
     return q, {"c": c, "k_pe": k_pe[..., 0, :]}
 
 
-def expanded(cfg, p, h, *, pos_offset=0, q_chunk: int = 0):
+def attend(cfg, p, h, *, prefix_len=None, q_chunk: int = 0):
     """Causal attention over the sequence ``h`` (B,T,d) with K and V
-    expanded per head.  Returns (out (B,T,d), its cache entries)."""
+    expanded per head; positions before ``prefix_len`` attend
+    bidirectionally.  Returns (out (B,T,d), its cache entries)."""
     bsz, t, _ = h.shape
     heads, rope = cfg.n_heads, cfg.qk_rope_dim
-    q, rows = _project(cfg, p, h, pos_offset + jnp.arange(t))
+    q, rows = _project(cfg, p, h, jnp.arange(t))
     c, k_pe = rows["c"], rows["k_pe"]
     k_nope = jnp.einsum("btr,rnh->btnh", c, p["wkb"])
     v = jnp.einsum("btr,rnh->btnh", c, p["wvb"])
     k = jnp.concatenate(
         [k_nope, jnp.broadcast_to(k_pe[:, :, None], (bsz, t, heads, rope))],
         -1)
-    out = attention(q, k, v, causal=True, q_chunk=q_chunk,
-                    scale=softmax_scale(cfg))
+    out = attention(q, k, v, causal=True, prefix_len=prefix_len,
+                    q_chunk=q_chunk, scale=softmax_scale(cfg))
     return jnp.einsum("btnh,nhd->btd", out, p["wo"]).astype(h.dtype), rows
 
 
-def absorbed(cfg, p, h, cache, cur_len):
+def attend_cached(cfg, p, h, cache, cur_len):
     """New tokens ``h`` (B,T,d) at positions ``cur_len ..`` against one
     layer's cache (``c`` (B,S,rank), ``k_pe`` (B,S,rope)), read in place:
     they attend to the cached positions before ``cur_len`` and to

@@ -24,7 +24,6 @@ from __future__ import annotations
 import math
 
 import jax
-import jax.ad_checkpoint
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
@@ -79,9 +78,6 @@ def _ep_body(x, router, w1, w3, w2, *, cfg, dp_axes, ep_axis, tp_axis, dsz):
     # ---- exchange: row block i goes to shard i
     recv_x = jax.lax.all_to_all(send_x, ep_axis, split_axis=0, concat_axis=0,
                                 tiled=False)
-    # Named for the save_a2a remat policy: saving the received activations
-    # keeps the backward from replaying the forward exchange.
-    recv_x = jax.ad_checkpoint.checkpoint_name(recv_x, "moe_a2a")
     recv_e = jax.lax.all_to_all(send_e, ep_axis, split_axis=0, concat_axis=0,
                                 tiled=False)
     rt = dsz * c_send
@@ -106,7 +102,6 @@ def _ep_body(x, router, w1, w3, w2, *, cfg, dp_axes, ep_axis, tp_axis, dsz):
         dsz, c_send, d)
     ret = jax.lax.all_to_all(back, ep_axis, split_axis=0, concat_axis=0,
                              tiled=False).reshape(dsz * c_send, d)
-    ret = jax.ad_checkpoint.checkpoint_name(ret, "moe_a2a")
     ret = jnp.concatenate([ret, jnp.zeros((1, d), x.dtype)], axis=0)
 
     # ---- weight + combine at the source

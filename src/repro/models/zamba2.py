@@ -6,15 +6,18 @@ attention+MLP weights; each application site concatenates the current hidden
 state with the original embedding ([h; emb] -> 2d) and maps it through a
 per-site input projector, per the Zamba2 design. KV caches exist only at the
 shared-block sites, which is what makes long_500k viable for this family.
+The shared block's attention is ``gqa``'s; its cache, ``cache["attn"]``, is
+a ``gqa`` cache with one entry a site, read in place by the decode step and
+written once after it.
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 
-from .common import (DTYPES, ParamBuilder, apply_rope, attention,
-                     cross_entropy, rms_norm, rope_angles, stack_layers,
-                     swiglu)
+from . import gqa
+from .common import (DTYPES, ParamBuilder, cross_entropy, rms_norm,
+                     stack_layers, swiglu)
 from .mamba2 import _dims, init_mamba2, mamba2_seq, mamba2_step
 from ..sharding.context import constrain
 
@@ -26,13 +29,9 @@ def _n_sites(cfg) -> int:
 
 
 def _init_shared_block(b: ParamBuilder, cfg) -> None:
-    d, hd = cfg.d_model, cfg.resolved_head_dim
-    nq, nkv = cfg.n_heads, cfg.n_kv_heads
+    d = cfg.d_model
     b.add("ln1", (d,), ("embed",), init="ones")
-    b.add("wq", (d, nq, hd), ("embed", "heads", "head_dim"))
-    b.add("wk", (d, nkv, hd), ("embed", "kv_heads", "head_dim"))
-    b.add("wv", (d, nkv, hd), ("embed", "kv_heads", "head_dim"))
-    b.add("wo", (nq, hd, d), ("heads", "head_dim", "embed"))
+    gqa.init_attn(b, cfg)
     b.add("ln2", (d,), ("embed",), init="ones")
     b.add("w1", (d, cfg.d_ff), ("embed", "ff"))
     b.add("w3", (d, cfg.d_ff), ("embed", "ff"))
@@ -60,47 +59,22 @@ def init(cfg, key: jax.Array):
 
 
 # ---------------------------------------------------------------- shared
-def _shared_attn(cfg, sp, site_proj, h, emb, *, cache_kv=None, cur_len=None,
+def _shared_attn(cfg, sp, site_proj, h, emb, *, cache=None, cur_len=None,
                  q_chunk=1024):
-    """One application of the shared block at a site."""
+    """One application of the shared block at a site.  Without ``cache``
+    the attention runs over the sequence; with it (decode) the new token
+    attends to the site's cache, read in place.  Returns (out, the tokens'
+    cache entries)."""
     x = jnp.concatenate([h, emb], axis=-1) @ site_proj       # (B,T,d)
     a = rms_norm(x, sp["ln1"], cfg.norm_eps)
-    hd = cfg.resolved_head_dim
-    q = jnp.einsum("btd,dnh->btnh", a, sp["wq"])
-    k = jnp.einsum("btd,dnh->btnh", a, sp["wk"])
-    v = jnp.einsum("btd,dnh->btnh", a, sp["wv"])
-    if cache_kv is None:
-        pos = jnp.arange(x.shape[1])
-        cos, sin = rope_angles(pos, hd, cfg.rope_theta)
-        q = apply_rope(q, cos[None], sin[None])
-        k = apply_rope(k, cos[None], sin[None])
-        out = attention(q, k, v, causal=True, q_chunk=q_chunk)
-        new_kv = (k, v)
+    if cache is None:
+        out, rows = gqa.attend(cfg, sp, a, q_chunk=q_chunk)
     else:
-        ck, cv = cache_kv
-        pos = cur_len + jnp.arange(1)
-        cos, sin = rope_angles(pos, hd, cfg.rope_theta)
-        q = apply_rope(q, cos[None], sin[None])
-        k = apply_rope(k, cos[None], sin[None])
-        ck = jax.lax.dynamic_update_slice(ck, k.astype(ck.dtype),
-                                          (0, cur_len, 0, 0))
-        cv = jax.lax.dynamic_update_slice(cv, v.astype(cv.dtype),
-                                          (0, cur_len, 0, 0))
-        smax = ck.shape[1]
-        nq, nkv = cfg.n_heads, cfg.n_kv_heads
-        qg = q.reshape(q.shape[0], 1, nkv, nq // nkv, hd)
-        scores = jnp.einsum("btkgh,bskh->bkgts", qg, ck).astype(jnp.float32)
-        scores = scores / jnp.sqrt(jnp.float32(hd))
-        valid = (jnp.arange(smax) <= cur_len)[None, None, None, None, :]
-        scores = jnp.where(valid, scores, -1e30)
-        probs = jax.nn.softmax(scores, axis=-1).astype(cv.dtype)
-        out = jnp.einsum("bkgts,bskh->btkgh", probs, cv)
-        out = out.reshape(q.shape[0], 1, nq, hd).astype(x.dtype)
-        new_kv = (ck, cv)
-    x = x + jnp.einsum("btnh,nhd->btd", out, sp["wo"]).astype(x.dtype)
+        out, rows = gqa.attend_cached(cfg, sp, a, cache, cur_len)
+    x = x + out
     m = rms_norm(x, sp["ln2"], cfg.norm_eps)
     x = x + swiglu(m, sp["w1"], sp["w3"], sp["w2"])
-    return x, new_kv
+    return x, rows
 
 
 # --------------------------------------------------------------- sequence
@@ -156,12 +130,10 @@ def train_loss(cfg, params, batch, rt=None):
 def init_cache(cfg, batch_size: int, max_len: int, dtype=None):
     dtype = dtype or DTYPES[cfg.dtype]
     d_in, h, pp, n = _dims(cfg)
-    n_sites = _n_sites(cfg)
-    hd, nkv = cfg.resolved_head_dim, cfg.n_kv_heads
     conv_dim = d_in + 2 * n
     return {
-        "kv_k": jnp.zeros((n_sites, batch_size, max_len, nkv, hd), dtype),
-        "kv_v": jnp.zeros((n_sites, batch_size, max_len, nkv, hd), dtype),
+        "attn": gqa.init_cache(cfg, _n_sites(cfg), batch_size, max_len,
+                               dtype),
         "ssm": jnp.zeros((cfg.n_layers, batch_size, h, pp, n), jnp.float32),
         "conv": jnp.zeros((cfg.n_layers, batch_size, cfg.ssm_conv_width - 1,
                            conv_dim), dtype),
@@ -170,8 +142,7 @@ def init_cache(cfg, batch_size: int, max_len: int, dtype=None):
 
 
 def cache_specs(cfg):
-    kv = ("sites", "batch", "kv_seq", "kv_heads", "head_dim")
-    return {"kv_k": kv, "kv_v": kv,
+    return {"attn": gqa.CACHE_AXES,
             "ssm": ("layers", "batch", "state_heads", "head_dim", "state"),
             "conv": ("layers", "batch", "conv", "inner"),
             "len": ()}
@@ -181,13 +152,13 @@ def prefill(cfg, params, batch, max_len: int, rt=None):
     q_chunk = getattr(rt, "q_chunk", 1024) if rt else 1024
     x = params["embed"][batch["tokens"]]
     emb = x
-    b, t, d = x.shape
+    t = x.shape[1]
     grouped = _group_layers(cfg, params["layers"])
 
     def outer_body(h, xs):
         site_proj, group = xs
-        shared_out, kv = _shared_attn(cfg, params["shared"], site_proj, h,
-                                      emb, q_chunk=q_chunk)
+        shared_out, rows = _shared_attn(cfg, params["shared"], site_proj, h,
+                                        emb, q_chunk=q_chunk)
         h = h + shared_out
 
         def inner_body(hh, lp):
@@ -195,17 +166,14 @@ def prefill(cfg, params, batch, max_len: int, rt=None):
             return hh + y, (ssm, conv)
 
         h, (ssm, conv) = jax.lax.scan(inner_body, h, group)
-        return h, (kv, ssm, conv)
+        return h, (rows, ssm, conv)
 
-    h, (kvs, ssm, conv) = jax.lax.scan(outer_body, x,
-                                       (params["site_proj"], grouped))
-    ks, vs = kvs
-    pad = max_len - t
-    ks = jnp.pad(ks, ((0, 0), (0, 0), (0, pad), (0, 0), (0, 0)))
-    vs = jnp.pad(vs, ((0, 0), (0, 0), (0, pad), (0, 0), (0, 0)))
+    h, (rows, ssm, conv) = jax.lax.scan(outer_body, x,
+                                        (params["site_proj"], grouped))
+    pad = ((0, 0), (0, 0), (0, max_len - t), (0, 0), (0, 0))
     L = cfg.n_layers
     cache = {
-        "kv_k": ks, "kv_v": vs,
+        "attn": jax.tree.map(lambda a: jnp.pad(a, pad), rows),
         "ssm": ssm.reshape((L,) + ssm.shape[2:]),
         "conv": conv.reshape((L,) + conv.shape[2:]),
         "len": jnp.int32(t),
@@ -225,10 +193,9 @@ def decode_step(cfg, params, batch, cache, rt=None):
     conv_g = cache["conv"].reshape((n_sites, every) + cache["conv"].shape[1:])
 
     def outer_body(h, xs):
-        site_proj, group, kv_k, kv_v, ssm, conv = xs
-        h2, (ck, cv) = _shared_attn(cfg, params["shared"], site_proj,
-                                    h[:, None], emb[:, None],
-                                    cache_kv=(kv_k, kv_v), cur_len=cur)
+        site_proj, group, site_cache, ssm, conv = xs
+        h2, new = _shared_attn(cfg, params["shared"], site_proj, h[:, None],
+                               emb[:, None], cache=site_cache, cur_len=cur)
         h = h + h2[:, 0]
 
         def inner_body(hh, xs2):
@@ -237,17 +204,16 @@ def decode_step(cfg, params, batch, cache, rt=None):
             return hh + y, (s2, cs2)
 
         h, (ssm2, conv2) = jax.lax.scan(inner_body, h, (group, ssm, conv))
-        return h, (ck, cv, ssm2, conv2)
+        return h, (new, ssm2, conv2)
 
-    h, (ks, vs, ssm, conv) = jax.lax.scan(
+    h, (new, ssm, conv) = jax.lax.scan(
         outer_body, x,
-        (params["site_proj"], grouped, cache["kv_k"], cache["kv_v"],
-         ssm_g, conv_g))
+        (params["site_proj"], grouped, cache["attn"], ssm_g, conv_g))
     h = rms_norm(h, params["final_norm"], cfg.norm_eps)
     logits = h @ params["head"]
     L = cfg.n_layers
     new_cache = {
-        "kv_k": ks, "kv_v": vs,
+        "attn": gqa.write(cache["attn"], new, cur),
         "ssm": ssm.reshape((L,) + ssm.shape[2:]),
         "conv": conv.reshape((L,) + conv.shape[2:]),
         "len": cur + 1,

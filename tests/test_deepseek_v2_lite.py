@@ -2,10 +2,11 @@
 the plain reference the benchmark uses (``bench/configs/deepseek-v2-lite.py``,
 loaded by path: one reference, not two).
 
-Latent attention's two forms, YaRN at the published settings, the dropless
-expert layer, one chip's share of the experts against the uncut layer, the
-serving path through ``serve.Engine`` with its counters, and
-``common.attention`` with values narrower than the queries."""
+Latent attention's two forms (and, beside them, GQA's), YaRN at the
+published settings, the dropless expert layer, one chip's share of the
+experts against the uncut layer, the serving path through ``serve.Engine``
+with its counters, and ``common.attention`` with values narrower than the
+queries."""
 import os
 import sys
 
@@ -15,7 +16,7 @@ import numpy as np
 import pytest
 
 from repro.configs import get_config
-from repro.models import Runtime, get_model, mla
+from repro.models import Runtime, get_model, gqa, mla
 from repro.models.common import attention, yarn_rope
 from repro.models.moe import moe_ffn
 from repro.serve import Engine, ServeConfig
@@ -75,17 +76,24 @@ def test_prefill_then_decode_matches_reference():
     assert int(cache["len"]) == s + steps
 
 
-def test_absorbed_decode_equals_expanded():
-    cfg = get_config("deepseek-v2-lite").smoke().replace(dtype="float32")
+@pytest.mark.parametrize("arch,module", [
+    ("deepseek-v2-lite", mla), ("internlm2-1.8b", gqa), ("qwen3-14b", gqa)])
+def test_absorbed_decode_equals_expanded(arch, module):
+    """Each attention module's two forms agree: a token decoded against
+    the cache of the positions before it (``attend_cached``) reads what
+    the whole-sequence form (``attend``) gives at its position, and
+    returns the cache entries that form made there.  qwen3-14b adds the
+    query/key norms."""
+    cfg = get_config(arch).smoke().replace(dtype="float32")
     params, _ = get_model(cfg).init(jax.random.PRNGKey(3))
     p = jax.tree.map(lambda a: a[0], params["layers"])
     b, t, first = 2, 10, 4
     h = jax.random.normal(jax.random.PRNGKey(4), (b, t, cfg.d_model))
-    out, rows = mla.expanded(cfg, p, h)
-    cache = {k: jnp.zeros((b, t + 3, v.shape[-1])).at[:, :t].set(v)
+    out, rows = module.attend(cfg, p, h)
+    cache = {k: jnp.zeros((b, t + 3) + v.shape[2:]).at[:, :t].set(v)
              for k, v in rows.items()}
     for pos in range(first, t):
-        o, new = mla.absorbed(cfg, p, h[:, pos:pos + 1], cache, pos)
+        o, new = module.attend_cached(cfg, p, h[:, pos:pos + 1], cache, pos)
         np.testing.assert_allclose(np.asarray(o[:, 0]), np.asarray(out[:, pos]),
                                    rtol=1e-5, atol=1e-5)
         for k, v in rows.items():

@@ -1,12 +1,10 @@
 """Probe-engine tests: scheduler contract, batch equivalence, golden topologies.
 
-The engine's correctness claim is strong: batching, caching, and concurrent
+The engine's correctness claim is strong: batching, caching, and
 scheduling must be *invisible* in the results — the engine-based
 ``discover_sim`` returns the same topology as the legacy sequential loop for
 a fixed device seed, and matches ground truth within the same tolerances.
 """
-import threading
-
 import numpy as np
 import pytest
 
@@ -22,6 +20,15 @@ KIB, MIB = 1024, 1024**2
 
 
 # --------------------------------------------------------------- scheduler
+# Dependency shapes, key -> deps, listed with dependents before their
+# inputs so that list order alone would violate them.
+SHAPES = {
+    "chain": {"d": ("c",), "c": ("b",), "b": ("a",), "a": ()},
+    "diamond": {"d": ("b", "c"), "c": ("a",), "b": ("a",), "a": ()},
+    "fan_in": {"d": ("a", "b", "c"), "a": (), "b": (), "c": ()},
+}
+
+
 class TestScheduler:
     def _items(self, log):
         def mk(name):
@@ -37,16 +44,26 @@ class TestScheduler:
             WorkItem(key="y", fn=mk("y"), deps=("a", "x")),
         ]
 
-    @pytest.mark.parametrize("workers", [0, 1, 4])
-    def test_dependency_order_respected(self, workers):
-        log = []
-        sched = run_work_items(self._items(log), max_workers=workers)
-        order = sched.order
-        assert set(order) == {"a", "b", "c", "x", "y"}
-        assert order.index("a") < order.index("b") < order.index("c")
-        assert order.index("a") < order.index("y")
-        assert order.index("x") < order.index("y")
-        assert sched.results == {k: k for k in "abcxy"}
+    @pytest.mark.parametrize("shape", sorted(SHAPES))
+    def test_dependency_order_respected(self, shape):
+        deps = SHAPES[shape]
+        seen = []
+
+        def mk(key):
+            def fn(results):
+                assert all(d in results for d in deps[key])
+                seen.append(key)
+                return key
+            return fn
+
+        sched = run_work_items([WorkItem(key=k, fn=mk(k), deps=d)
+                                for k, d in deps.items()])
+        assert sched.order == seen
+        assert set(sched.order) == set(deps)
+        for key, ds in deps.items():
+            assert all(sched.order.index(d) < sched.order.index(key)
+                       for d in ds)
+        assert sched.results == {k: k for k in deps}
 
     def test_unknown_dep_raises(self):
         with pytest.raises(ValueError, match="unknown deps"):
@@ -57,27 +74,15 @@ class TestScheduler:
         items = [WorkItem(key="a", fn=lambda r: 1, deps=("b",)),
                  WorkItem(key="b", fn=lambda r: 1, deps=("a",))]
         with pytest.raises(ValueError, match="cycle"):
-            run_work_items(items, max_workers=0)
+            run_work_items(items)
 
     def test_timings_accumulate_per_family(self):
         from repro.core.discover import DiscoveryTimings
         timings = DiscoveryTimings()
         log = []
-        run_work_items(self._items(log), max_workers=0, timings=timings)
+        run_work_items(self._items(log), timings=timings)
         assert timings.per_family.get("fam", 0) > 0
         assert timings.total >= timings.per_family["fam"]
-
-    def test_concurrent_runs_independent_items_in_parallel(self):
-        """Two GIL-releasing items must overlap under a 2-worker pool."""
-        barrier = threading.Barrier(2, timeout=5)
-
-        def fn(_results):
-            barrier.wait()   # deadlocks unless both run concurrently
-            return True
-
-        items = [WorkItem(key=i, fn=fn) for i in range(2)]
-        sched = run_work_items(items, max_workers=2)
-        assert all(sched.results.values())
 
 
 # ----------------------------------------------------------- sample cache
@@ -171,17 +176,6 @@ class TestBatchedRunner:
 
 
 # --------------------------------------------------- engine == legacy, golden
-def _topo_signature(topo):
-    out = []
-    for me in topo.memory:
-        attrs = {k: (a.value if not isinstance(a.value, list)
-                     else tuple(a.value), a.unit, a.provenance, a.confidence)
-                 for k, a in me.attrs.items()}
-        out.append((me.name, me.kind, me.scope, tuple(sorted(attrs.items())),
-                    tuple(me.shared_with)))
-    return out
-
-
 class TestEngineEqualsLegacy:
     @pytest.mark.parametrize("make,seed", [
         (make_h100_like, 11), (make_h100_like, 48),
@@ -215,12 +209,6 @@ class TestEngineEqualsLegacy:
         assert topology_equivalent(topo_a, topo_b)
         l1.attrs["load_latency"].value *= 1.01
         assert not topology_equivalent(topo_a, topo_b)
-
-    def test_concurrent_equals_inline(self):
-        dev = make_h100_like
-        topo_inline, _ = discover_sim(dev(seed=5), n_samples=9, max_workers=0)
-        topo_pool, _ = discover_sim(dev(seed=5), n_samples=9, max_workers=4)
-        assert _topo_signature(topo_inline) == _topo_signature(topo_pool)
 
     def test_cache_hits_counted_during_discovery(self):
         eng = run_probes(SimRunner(make_h100_like(seed=6)), n_samples=9,

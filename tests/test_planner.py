@@ -204,10 +204,9 @@ class TestPlannedDiscovery:
     def test_planner_vs_dense_topology(self, make, seed):
         """The bench-gated contract: whole-topology planner-vs-dense
         equivalence with confidence excluded, and strictly fewer rows."""
-        topo_d, td = discover_sim(make(seed=seed), n_samples=17,
-                                  max_workers=0)
+        topo_d, td = discover_sim(make(seed=seed), n_samples=17)
         topo_p, tp = discover_sim(make(seed=seed), n_samples=17,
-                                  max_workers=0, budget=SweepBudget())
+                                  budget=SweepBudget())
         assert topology_equivalent(topo_d, topo_p, rel_tol=1e-6,
                                    compare_confidence=False)
         assert tp.probe_rows < td.probe_rows
@@ -330,10 +329,9 @@ class TestSurveyMode:
     def test_survey_verifies_sibling_for_5x_fewer_rows(self, tmp_path):
         store = self._store(tmp_path)
         topo_full, t_full = discover_sim(make_h100_like(seed=48),
-                                         n_samples=17, max_workers=0,
-                                         store=store)
+                                         n_samples=17, store=store)
         topo_s, t_s = discover_sim(make_h100_like(seed=49), n_samples=17,
-                                   max_workers=0, store=store, survey=True)
+                                   store=store, survey=True)
         assert t_s.meta["survey"]["verified"] is True
         assert topology_equivalent(topo_full, topo_s, rel_tol=1e-6,
                                    compare_confidence=False)
@@ -349,22 +347,22 @@ class TestSurveyMode:
         assert entry.meta.get("survey_of")
         # and a repeat of the same survey request is a plain store hit
         _, t_again = discover_sim(make_h100_like(seed=49), n_samples=17,
-                                  max_workers=0, store=store, survey=True)
+                                  store=store, survey=True)
         assert t_again.probe_rows is None
 
     def test_survey_covers_cu_sharing_device(self, tmp_path):
         store = self._store(tmp_path)
         _, t_full = discover_sim(make_mi210_like(seed=7), n_samples=17,
-                                 max_workers=0, store=store)
+                                 store=store)
         _, t_s = discover_sim(make_mi210_like(seed=8), n_samples=17,
-                             max_workers=0, store=store, survey=True)
+                             store=store, survey=True)
         assert t_s.meta["survey"]["verified"] is True
         assert t_s.probe_rows * 5 <= t_full.probe_rows
 
     def test_survey_without_sibling_runs_full_discovery(self, tmp_path):
         store = self._store(tmp_path)
         topo, t = discover_sim(make_h100_like(seed=48), n_samples=17,
-                               max_workers=0, store=store, survey=True)
+                               store=store, survey=True)
         assert t.meta.get("survey") is None
         assert t.probe_rows is not None and t.probe_rows > 500
         assert topo.find_memory("L1") is not None
@@ -377,7 +375,7 @@ class TestSurveyMode:
 
         store = self._store(tmp_path)
         dev = make_h100_like(seed=48)
-        topo, _ = discover_sim(dev, n_samples=17, max_workers=0, store=store)
+        topo, _ = discover_sim(dev, n_samples=17, store=store)
         # doctor the stored reference's L1 size: the spot check must refuse
         key0 = request_key(sim_request_descriptor(dev, 17, None, None))
         bad = copy.deepcopy(topo)
@@ -386,7 +384,7 @@ class TestSurveyMode:
         store.put(key0, bad, meta={"request": "doctored"})
 
         topo_s, t_s = discover_sim(make_h100_like(seed=49), n_samples=17,
-                                   max_workers=0, store=store, survey=True)
+                                   store=store, survey=True)
         assert t_s.probe_rows is not None and t_s.probe_rows > 500
         for m in topo.memory:       # full re-measure, not the doctored copy
             ms = topo_s.find_memory(m.name)
@@ -429,7 +427,7 @@ class TestFusion:
         fams = ("sharing", "device_memory_latency",
                 "device_memory_bandwidth")
         a = run_probes(SimRunner(make_h100_like(seed=7)), n_samples=9,
-                       device_families=fams, max_workers=0)
+                       device_families=fams)
         b = run_probes(SimRunner(make_h100_like(seed=7)), n_samples=9,
                        device_families=fams, fuse=True)
         assert a.space_results.keys() == b.space_results.keys()

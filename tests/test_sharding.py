@@ -96,4 +96,4 @@ def test_kv_cache_sequence_sharded_for_serve():
     cache_logical = model.cache_specs()
     pspecs = tree_specs(cache_shapes, cache_logical, SERVE_RULES, mesh)
     # (L, B, S, kv, hd): batch over data, seq over model (kv=8 not div 16)
-    assert pspecs["k"] == P(None, "data", "model")
+    assert pspecs["layers"]["k"] == P(None, "data", "model")

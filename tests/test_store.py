@@ -7,8 +7,9 @@ import os
 import numpy as np
 import pytest
 
-from repro.core import (CATALOG, discover_sim, get_spec, make_h100_like,
-                        make_mi210_like)
+from repro.core import (CATALOG, discover_sim, discover_sim_legacy,
+                        get_spec, make_h100_like, make_mi210_like,
+                        topology_equivalent)
 from repro.core.discover import sim_request_descriptor
 from repro.core.engine import SampleCache
 from repro.core.engine.store import TopologyStore, request_key
@@ -149,12 +150,14 @@ class TestReadThrough:
         assert a == b
 
     def test_legacy_path_also_writes_through(self, store):
-        topo, _ = discover_sim(make_h100_like(seed=33), n_samples=9,
-                               store=store, engine=False)
+        """The legacy reference equals what the store serves after an
+        engine run of the same request (a hit probes nothing)."""
+        discover_sim(make_h100_like(seed=33), n_samples=9, store=store)
         dev = CountingDevice(make_h100_like(seed=33))
-        topo2, _ = discover_sim(dev, n_samples=9, store=store)
+        served, _ = discover_sim(dev, n_samples=9, store=store)
         assert dev.probe_calls == 0
-        assert topo2.to_json() == topo.to_json()
+        legacy, _ = discover_sim_legacy(make_h100_like(seed=33), n_samples=9)
+        assert topology_equivalent(legacy, served, rel_tol=1e-6)
 
 
 class TestCorruptionRecovery:

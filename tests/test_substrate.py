@@ -341,7 +341,8 @@ class TestServe:
         tokens = {"tokens": jnp.zeros((2, 1), jnp.int32)}
         memory = eng._decode.lower(params, tokens, cache).compile() \
             .memory_analysis()
-        kv_bytes = cache["k"].nbytes + cache["v"].nbytes
+        kv = cache["layers"]
+        kv_bytes = kv["k"].nbytes + kv["v"].nbytes
         assert memory.alias_size_in_bytes == kv_bytes + cache["len"].nbytes
 
         handed, decode = [], eng._decode
@@ -354,7 +355,8 @@ class TestServe:
         prompts = np.arange(12, dtype=np.int32).reshape(2, 6) % cfg.vocab_size
         eng.generate_batch(prompts, max_new=3)
         assert len(handed) == 3
-        assert all(c["k"].is_deleted() and c["v"].is_deleted() for c in handed)
+        assert all(c["layers"]["k"].is_deleted()
+                   and c["layers"]["v"].is_deleted() for c in handed)
 
     def test_continuous_batching_queue(self):
         cfg = get_config("internlm2-1.8b").smoke().replace(dtype="float32")

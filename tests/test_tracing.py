@@ -134,7 +134,7 @@ def test_family_spans_fill_timings(tmp_path, fuse):
     work item, and every bucket of ``DiscoveryTimings`` filled."""
     with jax.profiler.trace(str(tmp_path)):
         _, timings = discover_sim(make_h100_like(), n_samples=5,
-                                  elements=["L1"], fuse=fuse, max_workers=0)
+                                  elements=["L1"], fuse=fuse)
     spans = _spans(tmp_path)
     names = {s[0] for s in spans if s[0].startswith("mt4g.family.")}
     assert names == {f"mt4g.family.{f}" for f in timings.per_family}
